@@ -16,14 +16,19 @@ Two deliberate normalizations (the invariants checked are unaffected):
 
   * a chained branch whose lhs comes from OUTSIDE the launch (a previous
     launch's panel composite, a materialized env value) is specced as a
-    packed-x source — the panel-descriptor block numbering needs the
-    executor's env, which a static pass does not have, and the wave /
-    ring schedule is invariant to the lhs source tag;
+    packed-x source (``analysis.budgets.chained_spec``) — the
+    panel-descriptor block numbering needs the executor's env, which a
+    static pass does not have, and the wave / ring schedule is invariant
+    to the lhs source tag;
   * ragged-M serving launches are verified at the full bucket M — the
     offset table is identical for every request mix in the bucket, and
     the chained masked obligations (mrow slot addressing, in-image tap
     identity) are checked for ALL image-aligned cutoffs at once by
     ``hazards.check_chained_masked``.
+
+An SMEM-chunked group is verified one chunk at a time: its tables are
+built at ``ExecGroup.chunk_rows``, and every launch's prefetch must fit
+the SMEM budget (``analysis.budgets.group_smem_bytes``).
 
 Geometry checks are memoized: plans re-lower the same shapes constantly
 (every pytest case, every serve bucket) and the tables are pure
@@ -153,17 +158,18 @@ def _verify_grouped(graph, g, where, direction):
     pools = {b: p for b, p in g.pools}
     taps = tuple(_budgets.tap_count(graph.ops[_strip(pools[n])])
                  if n in pools else 1 for n in names)
+    rows = min(g.chunk_rows or m, m)      # each SMEM chunk's own table
     if direction == "bwd":
         # the combined masked-dx + dw/db launch: ONE uniform block size
         bl = grouped_block_shape(m, kns, dt)
         b = bl.bm if bl.bm == bl.bn == bl.bk else BLK
-        mb = _ceil(m, b)
+        mb = _ceil(rows, b)
         kbs = tuple(_ceil(k, b) for k, _ in kns)
         nbs = tuple(_ceil(n, b) for _, n in kns)
         raw = _checked_bwd(mb, kbs, nbs)
         return out + _findings(raw, "grouped-bwd", where)
     bl = grouped_block_shape(m, kns, dt)
-    mb = _ceil(m, bl.bm)
+    mb = _ceil(rows, bl.bm)
     kbs = tuple(_ceil(k, bl.bk) for k, _ in kns)
     nbs = tuple(_ceil(n, bl.bn) for _, n in kns)
     concat = bool(g.join)
@@ -205,92 +211,6 @@ def _verify_grouped(graph, g, where, direction):
     return out
 
 
-def _chained_spec(graph, g, where):
-    """Rebuild the hashable chained-launch spec ``_chain_static`` would
-    produce, from the plan + graph alone.  Returns (mb, spec, oh, ow,
-    nring, findings) — spec None when the chain is malformed."""
-    fam = "chained"
-    chain = [[_strip(n) for n in ph] for ph in g.chain]
-    opset = {n for ph in chain for n in ph}
-    pools = {_strip(b): _strip(p) for b, p in g.pools}
-    out = []
-
-    def dep_of(n):
-        preds = sorted(graph.pred[n])
-        if n in pools:
-            return pools[n]
-        if len(preds) != 1:
-            out.append(Finding("schema", fam, where,
-                               f"chained op {n} has {len(preds)} preds — "
-                               "a chain branch streams exactly one lhs"))
-            return None
-        return preds[0]
-
-    consumed = []
-    for ph in chain:
-        for n in ph:
-            d = dep_of(n)
-            if d is not None and d in opset and d not in consumed:
-                consumed.append(d)
-    ring_cols: dict[str, tuple] = {}
-    nxt = 0
-    for d in consumed:
-        nbb = _ceil(cm.gemm_shape(graph.ops[d])[2], BLK)
-        ring_cols[d] = tuple(range(nxt, nxt + nbb))
-        nxt += nbb
-    nring = max(nxt, 1)
-
-    first = graph.ops[chain[0][0]]
-    stride0 = first.p.get("stride", 1)
-    oh = _ceil(first.p["h"], stride0)
-    ow = _ceil(first.p["w"], stride0)
-    ms = {cm.gemm_shape(graph.ops[n])[0] for ph in chain for n in ph}
-    if len(ms) != 1:
-        out.append(Finding("schema", fam, where,
-                           f"chained phases disagree on shared M: "
-                           f"{sorted(ms)} — the wave schedule advances "
-                           "all phases over one row space"))
-        return None, None, oh, ow, nring, out
-    mb = _ceil(ms.pop(), BLK)
-
-    spec = []
-    for ph in chain:
-        pspec = []
-        for n in ph:
-            op = graph.ops[n]
-            _, kk, nn = cm.gemm_shape(op)
-            nbb = _ceil(nn, BLK)
-            d = dep_of(n)
-            if d in opset:
-                kh, kw = op.p.get("kh", 1), op.p.get("kw", 1)
-                if op.p.get("stride", 1) != 1:
-                    out.append(Finding(
-                        "schema", fam, where,
-                        f"ring consumer {n} has stride "
-                        f"{op.p['stride']} — the shifted-window ring "
-                        "only streams stride-1 taps"))
-                    return None, None, oh, ow, nring, out
-                taps = []
-                for dh in range(kh):
-                    for dw in range(kw):
-                        delta = (dh - kh // 2) * ow + (dw - kw // 2)
-                        if abs(delta) > BLK:
-                            out.append(Finding(
-                                "bounds", fam, where,
-                                f"ring consumer {n} halo {delta} exceeds "
-                                f"bm={BLK} (W={ow}, k={kh}x{kw}) — "
-                                "chain-ineligible geometry"))
-                            return None, None, oh, ow, nring, out
-                        taps.append((delta, dh - kh // 2, dw - kw // 2))
-                src = ("ring", (tuple(taps), ring_cols[d]))
-            else:
-                src = ("x", _ceil(kk, BLK))
-            pspec.append((src[0], src[1], nbb,
-                          tuple(ring_cols.get(n, ()))))
-        spec.append(tuple(pspec))
-    return mb, tuple(spec), oh, ow, nring, out
-
-
 def _verify_chained(graph, g, where, direction):
     if direction == "bwd":
         # reverse-phase mirror: ONE combined masked-dx + dw/db grouped
@@ -298,13 +218,18 @@ def _verify_chained(graph, g, where, direction):
         out = []
         for p, ph in enumerate(g.chain):
             sub = _verify_grouped(
-                graph, type(g)("grouped", tuple(ph), g.algorithms, 0.0),
+                graph, type(g)("grouped", tuple(ph), g.algorithms, 0.0,
+                               chunk_rows=g.chunk_rows),
                 f"{where}/phase{p}", "bwd")
             out += sub
         return out
-    mb, spec, oh, ow, nring, out = _chained_spec(graph, g, where)
+    mb, spec, oh, ow, nring, raw = _budgets.chained_spec(graph, g.chain,
+                                                         g.pools)
+    out = _findings(raw, "chained", where)
     if spec is None:
         return out
+    if g.chunk_rows:
+        mb = _ceil(g.chunk_rows, BLK)     # each chunk's own launch table
     return out + _findings(_checked_chained(mb, spec, oh, ow, nring),
                            "chained", where)
 
@@ -322,13 +247,44 @@ def _verify_experts(plan, where):
     return _findings(raw, "experts", where)
 
 
+def _verify_smem(graph, g, where, direction, budgets):
+    """Every launch of the group — one per SMEM chunk — must prefetch no
+    more than the SMEM budget, and the recorded chunk count must be the
+    one its ``chunk_rows`` gives.  A backward is checked only for plans
+    lowered for training (``lower(train=True)`` sizes the chunks for
+    both directions; an inference plan's backward mirror is pricing)."""
+    if direction == "bwd" and not budgets.get("train"):
+        return []
+    try:
+        launches = _budgets.group_launches(graph, g, (direction,))
+    except ValueError as e:
+        return [Finding("schema", g.mode, where, str(e))]
+    m = launches[0][0]
+    rows = min(g.chunk_rows or m, m)
+    out = []
+    if g.chunks != _ceil(m, rows):
+        out.append(Finding("bounds", g.mode, where,
+                           f"{g.chunks} chunks recorded, but {rows}-row "
+                           f"chunks of M={m} make {_ceil(m, rows)}"))
+    need = _budgets.group_smem_bytes(graph, g, rows, (direction,))
+    smem = budgets.get("smem", cm.SMEM_PREFETCH_BYTES)
+    if need > smem:
+        out.append(Finding("budget", g.mode, where,
+                           f"a {rows}-row launch prefetches {need}B of "
+                           f"offset tables into SMEM, over the {smem}B "
+                           "budget — the chip's compiler refuses it; "
+                           "chunk_rows should have split it"))
+    return out
+
+
 def _verify_budget(graph, g, where, direction, budgets):
     if not budgets:
         return []
     hbm, vmem = budgets["hbm"], budgets["vmem"]
+    out = _verify_smem(graph, g, where, direction, budgets)
     if g.mode == "grouped_chained":
         if direction == "bwd":
-            return []   # per-phase grouped launches, priced by the mirror
+            return out  # per-phase grouped launches, priced by the mirror
         chain = [[_strip(n) for n in ph] for ph in g.chain]
         opset = {n for ph in chain for n in ph}
         ring = frozenset(n for ph in chain for n in ph
@@ -343,12 +299,12 @@ def _verify_budget(graph, g, where, direction, budgets):
             include_gemm_ws=True if (direction == "fwd" and g.pools)
             else None)
     if not fp.fits(hbm, vmem):
-        return [Finding("budget", g.mode, where,
-                        f"footprint (ws={fp.workspace_bytes:.3g}B, "
-                        f"vmem={fp.vmem_bytes:.3g}B) exceeds the lowered "
-                        f"budgets (hbm={hbm:.3g}B, vmem={vmem:.3g}B) — "
-                        "this group should have been priced serial")]
-    return []
+        out.append(Finding("budget", g.mode, where,
+                           f"footprint (ws={fp.workspace_bytes:.3g}B, "
+                           f"vmem={fp.vmem_bytes:.3g}B) exceeds the lowered "
+                           f"budgets (hbm={hbm:.3g}B, vmem={vmem:.3g}B) — "
+                           "this group should have been priced serial"))
+    return out
 
 
 # ---------------------------------------------------------------------------

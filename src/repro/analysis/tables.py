@@ -106,6 +106,19 @@ def ch_mrow_row(nph: int) -> int:
     return CH_ROWS + 2 * nph
 
 
+def smem_bytes(shape) -> int:
+    """Bytes one int32 scalar-prefetch operand of ``shape`` occupies in
+    TPU SMEM, padding included, as the v5e compiler lays it out (read off
+    its allocation reports): a (R, T) table pads T to a multiple of 128
+    words and R to 1, 2, 4 or a multiple of 8; a vector pads to a
+    multiple of 1024 words."""
+    if len(shape) == 1:
+        return -(-shape[0] // 1024) * 1024 * 4
+    r, c = shape
+    rp = r if r <= 2 else 4 if r <= 4 else -(-r // 8) * 8
+    return rp * (-(-c // 128) * 128) * 4
+
+
 # ---------------------------------------------------------------------------
 # shared helpers
 

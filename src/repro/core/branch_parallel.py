@@ -74,7 +74,6 @@ def run_spatial(branches: Branches, x: jax.Array, mesh: jax.sharding.Mesh,
     x: (B, ...) — batch leading.  Output joined on all chips (replicated
     along ``axis``).
     """
-    from jax.experimental.shard_map import shard_map
 
     fns = list(branches.fns)
     g = len(fns)
@@ -101,8 +100,8 @@ def run_spatial(branches: Branches, x: jax.Array, mesh: jax.sharding.Mesh,
         (x.shape[0],) + x.shape[1:], x.dtype))
     out_rank = len(out_shape.shape)
     out_spec = P(*([None] * out_rank))
-    return shard_map(local, mesh=mesh, in_specs=(in_spec,),
-                     out_specs=out_spec, check_rep=False)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=out_spec, check_vma=False)(x)
 
 
 def run(branches: Branches, x: jax.Array, *, mode: str = "xla",

@@ -10,6 +10,8 @@ constants (TPU v5e-class, per assignment):
     ICI link bw      : 50e9   B/s per link
     VMEM             : 128 MiB per core (static-resource budget,
                        the SM register/smem analogue)
+    SMEM             : 1 MiB per core (holds every launch's
+                       scalar-prefetched offset tables)
 
 Per algorithm we model: FLOPs, HBM traffic (algorithm-dependent — direct
 conv re-reads the input per tap, im2col writes+reads the patch matrix,
@@ -25,11 +27,21 @@ import dataclasses
 
 from repro.core.graph import Op
 
+DEVICE_KIND = "TPU v5 lite"  # jax's device_kind for the v5e these describe
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # B/s per chip
 ICI_BW = 50e9                # B/s per link
 VMEM_BYTES = 128 * 1024 * 1024
+# scalar memory: the capacity the v5e compiler reports when a launch's
+# scalar-prefetch operands (offset tables) overflow it
+SMEM_BYTES = 1024 * 1024
+# what one launch's prefetched operands may claim — the rest stays free
+# for the scalars the compiler itself places in SMEM
+SMEM_PREFETCH_BYTES = SMEM_BYTES - 64 * 1024
 HBM_BYTES = 16 * 1024**3     # v5e-class per-chip HBM
+# per-launch fixed cost (dispatch + SMEM table fill) charged to every extra
+# launch an SMEM-chunked group makes — uncalibrated, like PIPELINE_LOSS
+LAUNCH_OVERHEAD = 5e-6
 
 # A single kernel cannot perfectly overlap its own DMA with its own MXU work:
 # intra-op dependencies (next block's compute needs this block's data) leave
@@ -678,6 +690,13 @@ def chained_time(phase_ops: list[list[Op]], ring=frozenset(),
         mb = mbl
     nph = len(phase_ops)
     return t * (1.0 + (nph - 1) / (mb + nph - 1))
+
+
+def chunked_time(t: float, chunks: int) -> float:
+    """Makespan of a group whose launch SMEM splits into ``chunks``
+    image-aligned M-chunks: the same work plus one ``LAUNCH_OVERHEAD``
+    per extra launch."""
+    return t + (chunks - 1) * LAUNCH_OVERHEAD
 
 
 def chained_time_bwd(phase_ops: list[list[Op]],

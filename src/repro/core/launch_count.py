@@ -55,10 +55,7 @@ def _walk(jaxpr, counts: dict) -> None:
 def _subjaxprs(v):
     """Yield every Jaxpr reachable from one params value (pjit's ``jaxpr``,
     custom-vjp call_jaxpr, scan/cond branches, ...)."""
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr  # jax >= 0.4.x
-    except ImportError:  # pragma: no cover - older jax layouts
-        from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, Jaxpr):

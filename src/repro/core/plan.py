@@ -111,6 +111,13 @@ class ExecGroup:
     # ``ops`` but not in ``chain``).  Phase p+1 branches whose producer
     # sits in phase p consume it through the in-kernel VMEM ring.
     chain: tuple[tuple[str, ...], ...] = ()
+    # SMEM chunking: each grouped-family launch of the group (both
+    # directions) runs as ``chunks`` launches over image-aligned row
+    # chunks of at most ``chunk_rows`` rows, so that every launch's offset
+    # table fits the chip's SMEM (``analysis.budgets.chunk_rows``).
+    # 0 / 1 = one launch over all M.
+    chunk_rows: int = 0
+    chunks: int = 1
 
     def __post_init__(self):
         if self.mode not in MODES:
@@ -399,22 +406,75 @@ def _chain_feasible(graph: OpGraph, phase0: list[str], branches: list[str],
 
 def _chain_budgets_ok(graph: OpGraph, phases: list[list[str]], ring, *,
                       hbm_budget: float, vmem_budget: float,
-                      block: int = 128) -> bool:
+                      block: int = 128, pools=(),
+                      train: bool = False) -> bool:
     """C2 re-check on the chained launch: the HBM workspace of its
     chained-priced GEMM lowering (ring consumers drop their patch buffer —
     their lhs never exists outside VMEM) plus the launch's ring scratch
     against the VMEM budget: 3 wave slots per ring column, the (3*bm, blk)
     shift window and the f32 accumulator.  The footprint itself comes
-    from ``analysis.budgets.chained_footprint``."""
-    return _budgets.chained_footprint(graph, phases, ring,
+    from ``analysis.budgets.chained_footprint``.  SMEM: a one-image chunk
+    of the launch (and, ``train``, of its backward) must fit — chunking
+    splits anything larger (``analysis.budgets.chunk_rows``)."""
+    if not _budgets.chained_footprint(graph, phases, ring,
                                       block=block).fits(hbm_budget,
-                                                        vmem_budget)
+                                                        vmem_budget):
+        return False
+    probe = ExecGroup("grouped_chained", tuple(n for ph in phases
+                                                for n in ph), {}, 0.0,
+                      pools=tuple(pools), chain=tuple(map(tuple, phases)))
+    try:
+        _budgets.chunk_rows(graph, probe, _directions(train))
+    except ValueError:
+        return False
+    return True
+
+
+def _directions(train: bool) -> tuple[str, ...]:
+    return ("fwd", "bwd") if train else ("fwd",)
+
+
+def _smem_chunks(graph: OpGraph, groups: list[ExecGroup], *,
+                 train: bool) -> list[ExecGroup]:
+    """Size every grouped-family group's SMEM chunks — the last lowering
+    pass, once each group's launch family (pooled, concat, chained) is
+    final.  A launch whose offset table would overflow SMEM splits into
+    image-aligned M-chunks (``ExecGroup.chunk_rows``/``chunks``), priced
+    as extra launches (``cost_model.chunked_time``); ``train`` sizes them
+    for the backward launches too.  A group in which one image's table
+    alone overflows is budget-infeasible, like a VMEM overflow: serial
+    (chained groups never get here — ``_chain_budgets_ok`` refuses)."""
+    out = []
+    for g in groups:
+        if g.mode not in ("grouped", "grouped_pooled", "grouped_concat",
+                          "grouped_chained"):
+            out.append(g)
+            continue
+        try:
+            rows, m = _budgets.chunk_rows(graph, g, _directions(train))
+        except ValueError as e:
+            assert g.mode != "grouped_chained", (g.ops, e)
+            profs = [cm.profile(graph.ops[n], g.algorithms[n])
+                     for n in g.ops]
+            out.append(ExecGroup("serial", g.ops, g.algorithms,
+                                 cm.serial_time(profs),
+                                 f"budget-infeasible (SMEM: {e})",
+                                 pools=g.pools))
+            continue
+        if rows < m:
+            n = -(-m // rows)
+            g = dataclasses.replace(
+                g, chunk_rows=rows, chunks=n,
+                modeled_time=cm.chunked_time(g.modeled_time, n),
+                reason=f"{g.reason}; SMEM: {n} launches of <= {rows} rows")
+        out.append(g)
+    return out
 
 
 def _chain_modules(graph: OpGraph, groups: list[ExecGroup], *,
                    hbm_budget: float = cm.HBM_BYTES * 0.25,
                    vmem_budget: float = cm.VMEM_BYTES,
-                   block: int = 128) -> list[ExecGroup]:
+                   block: int = 128, train: bool = False) -> list[ExecGroup]:
     """Chain grouped launches ACROSS module boundaries (the cross-module
     streaming pass, after ``_absorb_pools`` + ``_absorb_concat_joins``).
 
@@ -467,7 +527,8 @@ def _chain_modules(graph: OpGraph, groups: list[ExecGroup], *,
         ring = frozenset(branches)
         if not _chain_budgets_ok(graph, phases, ring,
                                  hbm_budget=hbm_budget,
-                                 vmem_budget=vmem_budget, block=block):
+                                 vmem_budget=vmem_budget, block=block,
+                                 pools=q.pools + pg.pools, train=train):
             continue
         phase_ops = [[graph.ops[n] for n in ph] for ph in phases]
         t = cm.chained_time(phase_ops, ring)
@@ -524,7 +585,8 @@ def _chain_modules(graph: OpGraph, groups: list[ExecGroup], *,
         ring = frozenset(run[1:])
         if not _chain_budgets_ok(graph, phases, ring,
                                  hbm_budget=hbm_budget,
-                                 vmem_budget=vmem_budget, block=block):
+                                 vmem_budget=vmem_budget, block=block,
+                                 train=train):
             continue
         phase_ops = [[graph.ops[n]] for n in run]
         t = cm.chained_time(phase_ops, ring)
@@ -645,10 +707,13 @@ def lower(graph: OpGraph, schedule: Schedule, *, mesh=None,
         # quad + concat-pair pairs and serial conv runs — into
         # grouped_chained groups (see ``_chain_modules``)
         groups = _chain_modules(graph, groups, hbm_budget=hbm_budget,
-                                vmem_budget=vmem_budget)
+                                vmem_budget=vmem_budget, train=train)
+    groups = _smem_chunks(graph, groups, train=train)
     plan = Plan(groups, context={"mesh": mesh, "graph": graph,
                                  "budgets": {"hbm": hbm_budget,
-                                             "vmem": vmem_budget}})
+                                             "vmem": vmem_budget,
+                                             "smem": cm.SMEM_PREFETCH_BYTES,
+                                             "train": train}})
     return _maybe_verify(plan, graph, verify)
 
 
@@ -756,15 +821,23 @@ def backward_plan(graph: OpGraph, plan: Plan, *,
                                     "grouped_pooled", "grouped_chained",
                                     "stacked")
                       else _REASON[g.mode])
+        if mode != "serial" and g.chunks > 1:
+            # the VJP's launches split at the forward's chunk rows
+            t = cm.chunked_time(t, g.chunks)
+            reason = f"{reason}; SMEM: {g.chunks} launches per direction"
         groups.append(ExecGroup(
             mode, tuple(f"grad:{n}" for n in g.ops),
             {f"grad:{n}": a for n, a in g.algorithms.items()}, t, reason,
+            chunk_rows=g.chunk_rows if mode != "serial" else 0,
+            chunks=g.chunks if mode != "serial" else 1,
             join=f"grad:{g.join}" if g.join else "",
             pools=tuple((f"grad:{b}", f"grad:{p}") for b, p in g.pools),
             chain=tuple(tuple(f"grad:{n}" for n in ph)
                         for ph in reversed(g.chain)) if g.chain else ()))
     bwd = Plan(groups, context={"forward": plan, "graph": graph,
-                                "budgets": {"hbm": hbm_budget,
+                                "budgets": {**plan.context.get("budgets",
+                                                               {}),
+                                            "hbm": hbm_budget,
                                             "vmem": vmem_budget}})
     return _maybe_verify(bwd, graph, verify)
 
@@ -1062,6 +1135,7 @@ def _run_grouped(group: ExecGroup, impls: dict[str, OpImpl], env: dict,
     from repro.kernels.ops import grouped_matmul_pooled
     names = group.ops
     pools = dict(group.pools)
+    cr = group.chunk_rows or None
     fusable = _grouped_fusable(impls, names)
     buckets = _dedup_buckets(impls, names, pools)
     if len(buckets) < len(names):
@@ -1081,10 +1155,11 @@ def _run_grouped(group: ExecGroup, impls: dict[str, OpImpl], env: dict,
                     jnp.concatenate([impls[n].gemm_bias for n in bk])
                     for bk in buckets]
             ys = grouped_matmul_pooled(xs, ws_b, bs_b, relu=True,
-                                       m_valid=mv, interpret=interpret)
+                                       m_valid=mv, interpret=interpret,
+                                       chunk_rows=cr)
         else:
             ys = grouped_matmul_pooled(xs, ws_b, m_valid=mv,
-                                       interpret=interpret)
+                                       interpret=interpret, chunk_rows=cr)
         for bk, y in zip(buckets, ys):
             off = 0
             for n in bk:
@@ -1100,11 +1175,12 @@ def _run_grouped(group: ExecGroup, impls: dict[str, OpImpl], env: dict,
         ys = grouped_matmul_pooled(xs, ws,
                                    [impls[n].gemm_bias for n in names],
                                    relu=True, m_valid=mv,
-                                   interpret=interpret)
+                                   interpret=interpret, chunk_rows=cr)
         for n, y in zip(names, ys):
             env[n] = impls[n].gemm_reshape(y)
     else:
-        ys = grouped_matmul_pooled(xs, ws, m_valid=mv, interpret=interpret)
+        ys = grouped_matmul_pooled(xs, ws, m_valid=mv, interpret=interpret,
+                                   chunk_rows=cr)
         for n, y in zip(names, ys):
             env[n] = impls[n].gemm_post(y)
 
@@ -1318,7 +1394,8 @@ def _run_grouped_chained(group: ExecGroup, impls: dict[str, OpImpl],
     mv = _valid_rows_from_m(m, valid_images, batch)
     outs = grouped_matmul_chained(phase_dicts, m=m, h=geom[0], w=geom[1],
                                   panels=tuple(panels), block=blk,
-                                  m_valid=mv, interpret=interpret)
+                                  m_valid=mv, interpret=interpret,
+                                  chunk_rows=group.chunk_rows or None)
     lay: dict[str, tuple[int, int, int]] = {}
     for p, ph in enumerate(group.chain):
         cb = 0
@@ -1373,7 +1450,7 @@ def _run_grouped_concat(group: ExecGroup, impls: dict[str, OpImpl], env: dict,
         xs, ws, [impls[n].gemm_bias for n in order],
         offsets=[offs[n] for n in order], total=off, relu=True,
         compact=False, m_valid=_valid_rows(xs, valid_images, batch),
-        interpret=interpret)
+        interpret=interpret, chunk_rows=group.chunk_rows or None)
     bn = grouped_block_shape(
         x0.shape[0], [(w.shape[0], w.shape[1]) for w in ws],
         x0.dtype).bn

@@ -32,7 +32,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.matmul import matmul_tiled
+from repro.kernels.matmul import matmul_tiled, mxu_precision
 from repro.kernels.branch_matmul import branch_matmul
 
 
@@ -116,6 +116,7 @@ def _direct_kernel(x_ref, w_ref, o_ref, *, kh, kw, stride, oh, ow, bh):
                 (stride, stride, 1))            # (bh, ow, C)
             acc += jnp.dot(window.reshape(bh * ow, c),
                            w_ref[i, j],
+                           precision=mxu_precision(window.dtype),
                            preferred_element_type=jnp.float32)
     o_ref[0] = acc.reshape(bh, ow, k).astype(o_ref.dtype)
 

@@ -110,7 +110,8 @@ from repro.analysis.tables import (
     EX_HJ, EX_OT, EX_RES,
     EB_BI, EB_DYT, EB_XT, EB_WHT, EB_WOT, EB_RES, EB_PH, EB_FIRST,
     EB_LAST, EB_PJ, EB_DXOT, EB_DWH, EB_DWO,
-    ch_out_i_row, ch_out_j_row, ch_mrow_row)
+    ch_out_i_row, ch_out_j_row, ch_mrow_row, smem_bytes)
+from repro.kernels.matmul import mxu_precision
 
 
 # Eager kernel launches by wrapper name — the benchmark's
@@ -226,6 +227,7 @@ def _gmm_kernel(tab_ref, *refs, relu: bool, masked: bool,
     if masked:
         x = jnp.where(m_ref[...] > 0, x, jnp.zeros_like(x))
     acc_ref[...] += jnp.dot(x, w_ref[...],
+                            precision=mxu_precision(x.dtype),
                             preferred_element_type=jnp.float32)
 
     @pl.when(tab_ref[GM_LAST, t] == 1)
@@ -382,9 +384,121 @@ def _ragged_index_maps(ragged: bool):
             lambda t, tab: (0, tab[GM_BJ, t]))
 
 
+# ---------------------------------------------------------------------------
+# SMEM: M-chunked launches
+# ---------------------------------------------------------------------------
+#
+# A launch prefetches its whole offset table into SMEM (plus the ragged
+# mrow vector and, chained, the dims pair), and the table has one column
+# per grid step — linear in M.  Past the chip's 1 MiB of SMEM the compiler
+# refuses the launch, so a launch whose table would not fit runs as
+# several launches over row chunks, each with a table that fits.  Chunks
+# need no halo: every grouped-family row is image-local (im2col rows,
+# pool taps and the chained ring's border-masked taps never read another
+# image), so chunk outputs stack along M, the backward's dW/db sum over
+# chunks and a ragged ``m_valid`` clips per chunk.  The plan layer sizes
+# the chunks (``ExecGroup.chunk_rows``) with these same functions;
+# ``chunk_rows=None`` sizes them here.
+
+def launch_smem_bytes(per_block, mb: int, mrow_slots: int = 1,
+                      fixed=()) -> int:
+    """SMEM bytes one launch over ``mb`` M-blocks prefetches: its offset
+    table — ``per_block`` is the (rows, steps) shape of the family's
+    table for ONE M-block, and every family's step count is linear in the
+    M-block count — plus the ragged mrow vector (``mrow_slots`` per
+    block; counted for dense launches too, so both chunk alike) and the
+    ``fixed`` operands' shapes."""
+    r, s = per_block
+    return (smem_bytes((r, mb * s))
+            + (smem_bytes((mrow_slots * mb,)) if mrow_slots else 0)
+            + sum(smem_bytes(f) for f in fixed))
+
+
+def _smem_budget() -> int:
+    from repro.core import cost_model
+    return cost_model.SMEM_PREFETCH_BYTES
+
+
+def smem_chunk_rows(m: int, bm: int, per_block, *, unit: int,
+                    mrow_slots: int = 1, fixed=()) -> int:
+    """Rows per launch: all ``m`` when the whole launch's prefetch fits
+    ``cost_model.SMEM_PREFETCH_BYTES``, else the largest multiple of
+    ``unit`` rows (an image, or an M-block) whose launch fits.  Raises
+    when one unit alone does not fit — no chunking makes that legal."""
+    budget = _smem_budget()
+
+    def fits(rows):
+        return launch_smem_bytes(per_block, -(-rows // bm), mrow_slots,
+                                 fixed) <= budget
+    if fits(m):
+        return m
+    if not fits(unit):
+        raise ValueError(
+            f"one {unit}-row unit's offset table ({per_block[0]} rows x "
+            f"{per_block[1]} steps per M-block) exceeds the "
+            f"{budget}-byte SMEM prefetch budget")
+    lo, hi = 1, -(-m // unit)            # lo units fit, hi do not
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        if fits(mid * unit):
+            lo = mid
+        else:
+            hi = mid
+    return lo * unit
+
+
+def _launch_rows(m: int, bm: int, per_block, chunk_rows, *, unit: int,
+                 mrow_slots: int = 1, fixed=()) -> int:
+    """Rows per launch for this call: the planned ``chunk_rows`` (which
+    must fit SMEM), else ``smem_chunk_rows`` over ``unit``-row units."""
+    if chunk_rows is None:
+        return smem_chunk_rows(m, bm, per_block, unit=unit,
+                               mrow_slots=mrow_slots, fixed=fixed)
+    rows = min(int(chunk_rows), m)
+    need = launch_smem_bytes(per_block, -(-rows // bm), mrow_slots, fixed)
+    if need > _smem_budget():
+        raise ValueError(
+            f"planned chunk of {rows} rows needs {need} SMEM bytes; the "
+            f"prefetch budget is {_smem_budget()}")
+    return rows
+
+
+def _row_spans(m: int, rows: int):
+    return [(s, min(rows, m - s)) for s in range(0, m, rows)]
+
+
+def _chunk_valid(m_valid, start: int, rows: int):
+    if m_valid is None:
+        return None
+    return jnp.clip(jnp.asarray(m_valid, jnp.int32) - start, 0, rows)
+
+
+def _stack_rows(parts, total: int | None = None):
+    """Chunk outputs stacked along M into ``total`` rows (default: their
+    sum; extra rows are zero) with dynamic_update_slice — no concatenate,
+    which the traced launch counter charges as a join copy."""
+    total = sum(p.shape[0] for p in parts) if total is None else total
+    out = jnp.zeros((total,) + parts[0].shape[1:], parts[0].dtype)
+    off = 0
+    for p in parts:
+        out = jax.lax.dynamic_update_slice(out, p,
+                                           (off,) + (0,) * (p.ndim - 1))
+        off += p.shape[0]
+    return out
+
+
+def _sum_parts(parts):
+    """Per-chunk partial dW/db summed in f32, returned in their dtype."""
+    acc = parts[0].astype(jnp.float32)
+    for p in parts[1:]:
+        acc = acc + p.astype(jnp.float32)
+    return acc.astype(parts[0].dtype)
+
+
 def grouped_matmul(xs, ws, bs=None, *, relu: bool = False, mask=None,
                    m_valid=None, bm: int | None = None, bn: int | None = None,
-                   bk: int | None = None, interpret: bool = False):
+                   bk: int | None = None, interpret: bool = False,
+                   chunk_rows: int | None = None):
     """[x_g @ w_g (+ b_g) (+ ReLU)] for ragged (K_g, N_g), one kernel.
 
     xs: G arrays (M, K_g) — shared M; ws: G arrays (K_g, N_g);
@@ -394,7 +508,9 @@ def grouped_matmul(xs, ws, bs=None, *, relu: bool = False, mask=None,
     scalar) makes the launch ragged-M: rows at/past it are padding and
     the epilogue stores zeros there (``_ragged_mrows``) — the serving
     path's bucketed multi-request batches.  Block sizes default to
-    ``grouped_block_shape``.  Returns G arrays (M, N_g).
+    ``grouped_block_shape``.  ``chunk_rows`` caps the rows per launch
+    (SMEM chunking; None sizes it from the table).  Returns G arrays
+    (M, N_g).
     """
     g = len(xs)
     assert g == len(ws) and g >= 1, (len(xs), len(ws))
@@ -413,6 +529,18 @@ def grouped_matmul(xs, ws, bs=None, *, relu: bool = False, mask=None,
     kps = [_round_up(x.shape[1], bk) for x in xs]
     nps = [_round_up(w.shape[1], bn) for w in ws]
     nsum = sum(nps)
+    kbs = tuple(kp // bk for kp in kps)
+    nbs = tuple(np_ // bn for np_ in nps)
+    rows = _launch_rows(m, bm, _plan_tiles(1, kbs, nbs).shape, chunk_rows,
+                        unit=bm)
+    if rows < m:
+        parts = [grouped_matmul(
+            [x[s:s + r] for x in xs], ws, bs, relu=relu,
+            mask=None if mask is None else [mk[s:s + r] for mk in mask],
+            m_valid=_chunk_valid(m_valid, s, r), bm=bm, bn=bn, bk=bk,
+            interpret=interpret, chunk_rows=r)
+            for s, r in _row_spans(m, rows)]
+        return [_stack_rows([p[i] for p in parts]) for i in range(g)]
 
     def pack_x(arrs):
         return jnp.concatenate(
@@ -433,10 +561,8 @@ def grouped_matmul(xs, ws, bs=None, *, relu: bool = False, mask=None,
              for b, np_ in zip(bs, nps)]).reshape(1, nsum).astype(xpk.dtype)
 
     _count_launch("grouped_matmul")
-    tab = _device_table(
-        _plan_tiles,
-        mb, tuple(kp // bk for kp in kps), tuple(np_ // bn for np_ in nps))
-    o_tiles = mb * sum(np_ // bn for np_ in nps)
+    tab = _device_table(_plan_tiles, mb, kbs, nbs)
+    o_tiles = mb * sum(nbs)
 
     ragged = m_valid is not None
     ix, ixb = _ragged_index_maps(ragged)
@@ -561,7 +687,8 @@ def grouped_matmul_concat(xs, ws, bs=None, *, offsets, total: int,
                           relu: bool = False, compact: bool = True,
                           m_valid=None, bm: int | None = None,
                           bn: int | None = None, bk: int | None = None,
-                          interpret: bool = False):
+                          interpret: bool = False,
+                          chunk_rows: int | None = None):
     """[x_g @ w_g (+ b_g) (+ ReLU)] assembled into the fork/join's concat
     layout — ONE (M, total) output, branch g's columns at ``offsets[g]``.
 
@@ -583,8 +710,9 @@ def grouped_matmul_concat(xs, ws, bs=None, *, offsets, total: int,
     cumulative padded base — for callers that splice the passthrough
     segments and strip the padding in one pass (``core/plan.py``'s
     grouped_concat executor); ``offsets``/``total`` then only fix the
-    branch order.  ``m_valid`` as in ``grouped_matmul`` (ragged-M
-    epilogue mask: rows at/past it store zeros).
+    branch order.  ``m_valid`` and ``chunk_rows`` as in
+    ``grouped_matmul`` (ragged-M epilogue mask: rows at/past it store
+    zeros; SMEM chunking).
     """
     g = len(xs)
     assert g == len(ws) and g == len(offsets) and g >= 1
@@ -605,6 +733,16 @@ def grouped_matmul_concat(xs, ws, bs=None, *, offsets, total: int,
     kps = [_round_up(x.shape[1], bk) for x in xs]
     nps = [_round_up(n, bn) for n in ns]
     nsum = sum(nps)
+    kbs = tuple(kp // bk for kp in kps)
+    nbs = tuple(np_ // bn for np_ in nps)
+    rows = _launch_rows(m, bm, _plan_tiles_concat(1, kbs, nbs).shape,
+                        chunk_rows, unit=bm)
+    if rows < m:
+        return _stack_rows([grouped_matmul_concat(
+            [x[s:s + r] for x in xs], ws, bs, offsets=offsets, total=total,
+            relu=relu, compact=compact, m_valid=_chunk_valid(m_valid, s, r),
+            bm=bm, bn=bn, bk=bk, interpret=interpret, chunk_rows=r)
+            for s, r in _row_spans(m, rows)])
 
     xpk = jnp.concatenate(
         [_tile_stack(jnp.pad(x, ((0, mp - m), (0, kp - x.shape[1]))),
@@ -622,10 +760,8 @@ def grouped_matmul_concat(xs, ws, bs=None, *, offsets, total: int,
              for b, np_ in zip(bs, nps)]).reshape(1, nsum).astype(xpk.dtype)
 
     _count_launch("grouped_matmul_concat")
-    tab = _device_table(
-        _plan_tiles_concat,
-        mb, tuple(kp // bk for kp in kps), tuple(np_ // bn for np_ in nps))
-    ncbt = sum(np_ // bn for np_ in nps)
+    tab = _device_table(_plan_tiles_concat, mb, kbs, nbs)
+    ncbt = sum(nbs)
 
     ragged = m_valid is not None
     ix, ixb = _ragged_index_maps(ragged)
@@ -787,6 +923,7 @@ def _gmm_pooled_kernel(tab_ref, *refs, relu: bool, ragged: bool = False):
         x = jnp.where(tab_ref[GP_UPOOL, t] == 1,
                       pool_ref[ps].astype(x.dtype), x)
         acc_ref[...] += jnp.dot(x, w_ref[...],
+                                precision=mxu_precision(x.dtype),
                                 preferred_element_type=jnp.float32)
 
         @pl.when(tab_ref[GP_LAST, t] == 1)
@@ -931,7 +1068,7 @@ def _branch_taps(xs, tap_limit: int | None = None):
 
 def _pooled_launch(xs, ws, bs, *, relu, concat, offsets=None, total=None,
                    compact=True, m_valid=None, bm=None, bn=None, bk=None,
-                   interpret=False, tap_limit=None):
+                   interpret=False, tap_limit=None, chunk_rows=None):
     """Shared implementation of the pooled grouped launch (plain and
     fused-concat output layouts)."""
     g = len(xs)
@@ -958,6 +1095,22 @@ def _pooled_launch(xs, ws, bs, *, relu, concat, offsets=None, total=None,
     kps = [_round_up(tl[0].shape[1], bk) for tl in tls]
     nps = [_round_up(n, bn) for n in ns]
     nsum = sum(nps)
+    kbs = tuple(kp // bk for kp in kps)
+    nbs = tuple(np_ // bn for np_ in nps)
+    rows = _launch_rows(
+        m, bm, _plan_tiles_pooled(1, kbs, nbs, tuple(tns), concat).shape,
+        chunk_rows, unit=bm)
+    if rows < m:
+        parts = [_pooled_launch(
+            [[t[s:s + r] for t in tl] if tn > 1 else tl[0][s:s + r]
+             for tl, tn in zip(tls, tns)], ws, bs, relu=relu,
+            concat=concat, offsets=offsets, total=total, compact=compact,
+            m_valid=_chunk_valid(m_valid, s, r), bm=bm, bn=bn, bk=bk,
+            interpret=interpret, tap_limit=tap_limit, chunk_rows=r)
+            for s, r in _row_spans(m, rows)]
+        if concat:
+            return _stack_rows(parts)
+        return [_stack_rows([p[i] for p in parts]) for i in range(g)]
 
     # X stack: branch g's region holds, tile by tile, its taps
     # consecutively — (i, kk)-tile slots [base + (i*nkb + kk)*taps, +taps)
@@ -986,10 +1139,8 @@ def _pooled_launch(xs, ws, bs, *, relu, concat, offsets=None, total=None,
     name = "grouped_matmul_pooled_concat" if concat \
         else "grouped_matmul_pooled"
     _count_launch(name)
-    tab = _device_table(
-        _plan_tiles_pooled,
-        mb, tuple(kp // bk for kp in kps), tuple(np_ // bn for np_ in nps),
-        tuple(tns), concat)
+    tab = _device_table(_plan_tiles_pooled, mb, kbs, nbs, tuple(tns),
+                        concat)
     nkb_pool = max((kp // bk for kp, tn in zip(kps, tns) if tn > 1),
                    default=1)
     o_tiles = mb * sum(np_ // bn for np_ in nps)
@@ -1039,7 +1190,8 @@ def grouped_matmul_pooled(xs, ws, bs=None, *, relu: bool = False,
                           m_valid=None, bm: int | None = None,
                           bn: int | None = None, bk: int | None = None,
                           interpret: bool = False,
-                          tap_limit: int | None = None):
+                          tap_limit: int | None = None,
+                          chunk_rows: int | None = None):
     """[maxpool(x_g) @ w_g (+ b_g) (+ ReLU)] for ragged (K_g, N_g) in ONE
     launch, the maxpool computed IN-KERNEL as a pre-GEMM stage.
 
@@ -1050,16 +1202,18 @@ def grouped_matmul_pooled(xs, ws, bs=None, *, relu: bool = False,
     activation never materializes in HBM and no standalone pooling launch
     remains.  Branches whose tap count exceeds ``tap_limit`` (default
     ``POOL_TAP_LIMIT``) fold at pack time instead — see the constant's
-    comment.  ``m_valid`` as in ``grouped_matmul`` (ragged-M epilogue
-    mask).  With no pooled branch this is exactly ``grouped_matmul``.
-    Returns G arrays (M, N_g).
+    comment.  ``m_valid`` and ``chunk_rows`` as in ``grouped_matmul``
+    (ragged-M epilogue mask; SMEM chunking).  With no pooled branch this
+    is exactly ``grouped_matmul``.  Returns G arrays (M, N_g).
     """
     if all(not isinstance(x, (list, tuple)) for x in xs):
         return grouped_matmul(xs, ws, bs, relu=relu, m_valid=m_valid,
-                              bm=bm, bn=bn, bk=bk, interpret=interpret)
+                              bm=bm, bn=bn, bk=bk, interpret=interpret,
+                              chunk_rows=chunk_rows)
     return _pooled_launch(xs, ws, bs, relu=relu, concat=False,
                           m_valid=m_valid, bm=bm, bn=bn, bk=bk,
-                          interpret=interpret, tap_limit=tap_limit)
+                          interpret=interpret, tap_limit=tap_limit,
+                          chunk_rows=chunk_rows)
 
 
 def grouped_matmul_pooled_concat(xs, ws, bs=None, *, offsets, total: int,
@@ -1068,23 +1222,26 @@ def grouped_matmul_pooled_concat(xs, ws, bs=None, *, offsets, total: int,
                                  bn: int | None = None,
                                  bk: int | None = None,
                                  interpret: bool = False,
-                                 tap_limit: int | None = None):
+                                 tap_limit: int | None = None,
+                                 chunk_rows: int | None = None):
     """``grouped_matmul_concat`` with the in-kernel pool stage: pooled
     branches' epilogues land in the join's [M, total] layout like every
     other branch — one launch covers pooling, GEMMs, bias+ReLU AND the
-    concat.  ``xs``/``compact``/``m_valid`` semantics as in the
-    pooled/concat wrappers.  With no pooled branch this is
+    concat.  ``xs``/``compact``/``m_valid``/``chunk_rows`` semantics as
+    in the pooled/concat wrappers.  With no pooled branch this is
     ``grouped_matmul_concat``."""
     if all(not isinstance(x, (list, tuple)) for x in xs):
         return grouped_matmul_concat(xs, ws, bs, offsets=offsets,
                                      total=total, relu=relu,
                                      compact=compact, m_valid=m_valid,
                                      bm=bm, bn=bn, bk=bk,
-                                     interpret=interpret)
+                                     interpret=interpret,
+                                     chunk_rows=chunk_rows)
     return _pooled_launch(xs, ws, bs, relu=relu, concat=True,
                           offsets=offsets, total=total, compact=compact,
                           m_valid=m_valid, bm=bm, bn=bn, bk=bk,
-                          interpret=interpret, tap_limit=tap_limit)
+                          interpret=interpret, tap_limit=tap_limit,
+                          chunk_rows=chunk_rows)
 
 
 def grouped_matmul_pooled_ref(xs, ws, bs=None, *, relu: bool = False,
@@ -1133,6 +1290,7 @@ def _gmm_dw_kernel(tab_ref, *refs, masked: bool):
     # x^T @ dy: contract the shared m-rows of both tiles -> (bk, bn)
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], dy, dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=mxu_precision(dy.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(tab_ref[DW_DODB, t] == 1)
@@ -1212,6 +1370,8 @@ def grouped_matmul_dw(xs, dys, mask=None, *, bm: int | None = None,
     kps = [_round_up(k, bk) for k, _ in kns]
     nps = [_round_up(n, bn) for _, n in kns]
     nsum = sum(nps)
+    kbs = tuple(kp // bk for kp in kps)
+    nbs = tuple(np_ // bn for np_ in nps)
 
     xpk = jnp.concatenate(
         [_tile_stack(jnp.pad(x, ((0, mp - m), (0, kp - x.shape[1]))),
@@ -1237,9 +1397,7 @@ def grouped_matmul_dw(xs, dys, mask=None, *, bm: int | None = None,
             pl.BlockSpec((None, bm, bn), lambda t, tab: (tab[DW_DYT, t], 0, 0)))
 
     _count_launch("grouped_matmul_dw")
-    tab = _device_table(
-        _plan_tiles_dw,
-        mb, tuple(kp // bk for kp in kps), tuple(np_ // bn for np_ in nps))
+    tab = _device_table(_plan_tiles_dw, mb, kbs, nbs)
     w_tiles = sum((kp // bk) * (np_ // bn) for kp, np_ in zip(kps, nps))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1306,6 +1464,7 @@ def _gmm_bwd_kernel(tab_ref, dy_ref, ab_ref, o_ref, db_ref,
     @pl.when(~is_dw)
     def _acc_dx():
         acc_ref[...] += jnp.dot(dy, ab_ref[...],
+                                precision=mxu_precision(dy.dtype),
                                 preferred_element_type=jnp.float32)
 
     # phase 1 — dw_g = x_g^T @ dy_g: ab is the X tile; db on k-row 0
@@ -1313,6 +1472,7 @@ def _gmm_bwd_kernel(tab_ref, dy_ref, ab_ref, o_ref, db_ref,
     def _acc_dw():
         acc_ref[...] += jax.lax.dot_general(
             ab_ref[...], dy, dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=mxu_precision(dy.dtype),
             preferred_element_type=jnp.float32)
 
     @pl.when(is_dw & first & dodb)
@@ -1399,7 +1559,8 @@ def _plan_tiles_bwd(m_blocks: int, kbs: tuple[int, ...],
 
 
 def grouped_matmul_bwd(xs, ws, dys, mask=None, *, block: int | None = None,
-                       interpret: bool = False):
+                       interpret: bool = False,
+                       chunk_rows: int | None = None):
     """The whole grad CoGroup of a grouped branch group in ONE launch:
     dx_g = (dy_g ⊙ mask_g) @ w_g^T, dw_g = x_g^T @ (dy_g ⊙ mask_g),
     db_g = sum_M (dy_g ⊙ mask_g), over a concatenated two-phase offset
@@ -1415,8 +1576,9 @@ def grouped_matmul_bwd(xs, ws, dys, mask=None, *, block: int | None = None,
     xs: G arrays (M, K_g) — forward GEMM inputs; ws: G arrays (K_g, N_g);
     dys: G arrays (M, N_g); mask: optional G arrays (M, N_g) — the
     fused-ReLU cotangent mask (dy zeroed where mask <= 0, both phases).
-    Returns (dxs, dws, dbs): G×(M, K_g), G×(K_g, N_g) in the input dtype
-    and G float32 (N_g,).
+    ``chunk_rows`` as in ``grouped_matmul``: SMEM chunks stack their dx
+    rows and sum their dW/db.  Returns (dxs, dws, dbs): G×(M, K_g),
+    G×(K_g, N_g) in the input dtype and G float32 (N_g,).
     """
     g = len(xs)
     assert g == len(ws) == len(dys) and g >= 1, (len(xs), len(ws), len(dys))
@@ -1438,6 +1600,19 @@ def grouped_matmul_bwd(xs, ws, dys, mask=None, *, block: int | None = None,
     kps = [_round_up(k, b) for k, _ in kns]
     nps = [_round_up(n, b) for _, n in kns]
     nsum = sum(nps)
+    kbs = tuple(kp // b for kp in kps)
+    nbs = tuple(np_ // b for np_ in nps)
+    rows = _launch_rows(m, b, _plan_tiles_bwd(1, kbs, nbs).shape,
+                        chunk_rows, unit=b, mrow_slots=0)
+    if rows < m:
+        parts = [grouped_matmul_bwd(
+            [x[s:s + r] for x in xs], ws, [dy[s:s + r] for dy in dys],
+            None if mask is None else [mk[s:s + r] for mk in mask],
+            block=b, interpret=interpret, chunk_rows=r)
+            for s, r in _row_spans(m, rows)]
+        return ([_stack_rows([p[0][i] for p in parts]) for i in range(g)],
+                [_sum_parts([p[1][i] for p in parts]) for i in range(g)],
+                [_sum_parts([p[2][i] for p in parts]) for i in range(g)])
 
     if mask is not None:
         assert all(mk.shape == dy.shape for mk, dy in zip(mask, dys))
@@ -1457,9 +1632,7 @@ def grouped_matmul_bwd(xs, ws, dys, mask=None, *, block: int | None = None,
            for x, kp in zip(xs, kps)], axis=0).astype(dypk.dtype)
 
     _count_launch("grouped_matmul_bwd")
-    tab = _device_table(
-        _plan_tiles_bwd,
-        mb, tuple(kp // b for kp in kps), tuple(np_ // b for np_ in nps))
+    tab = _device_table(_plan_tiles_bwd, mb, kbs, nbs)
     dx_tiles = mb * sum(kp // b for kp in kps)
     w_tiles = sum((kp // b) * (np_ // b) for kp, np_ in zip(kps, nps))
 
@@ -1721,6 +1894,7 @@ def _gmm_chained_kernel(*args, nphases: int, npanels: int, bm: int,
         for pi, p_ref in enumerate(p_refs):
             xop = jnp.where(src == 3 + pi, p_ref[...], xop)
         acc_ref[...] += jnp.dot(xop, w_ref[...],
+                                precision=mxu_precision(xop.dtype),
                                 preferred_element_type=jnp.float32)
 
     def _store():
@@ -1811,7 +1985,8 @@ def _chain_static(phases, blk, bm, wimg):
 def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
                            block: int = 128, m_valid=None,
                            debug_steps: bool = False,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           chunk_rows: int | None = None):
     """Execute a chain of grouped branch phases as ONE kernel.
 
     ``phases``: list of phases, each a list of branch dicts
@@ -1850,6 +2025,10 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
     (the skip instrument): ``(panels, steps)`` where ``steps`` is a
     (1, 1) i32 of grid steps that ran their body — dense launches count
     every step, ragged launches only live-block steps.
+
+    ``chunk_rows`` caps the rows per launch (SMEM chunking, a multiple of
+    the h*w image; None sizes it from the table): the chain then runs as
+    one launch per image-aligned chunk, outputs stacked along M.
     """
     blk = block
     bm = blk
@@ -1865,6 +2044,31 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
         dtype = panels[0].dtype if panels else phases[0][0]["w"].dtype
     spec = _chain_static(phases, blk, bm, w)
     nph = len(phases)
+    hw = h * w
+    rows = _launch_rows(m, bm, _plan_tiles_chained(1, spec).shape,
+                        chunk_rows, unit=hw, mrow_slots=nph, fixed=((2,),))
+    if rows < m:
+        # image-aligned chunks: the ring's border mask decodes rows
+        # relative to the launch, so every chunk must start an image
+        if rows % hw:
+            raise ValueError(f"chained chunk of {rows} rows is not a "
+                             f"multiple of the {hw}-row image")
+        parts = []
+        for s, r in _row_spans(m, rows):
+            sub = [[dict(br, src=("x", [a[s:s + r] for a in br["src"][1]]))
+                    if br["src"][0] == "x" else br for br in phase]
+                   for phase in phases]
+            parts.append(grouped_matmul_chained(
+                sub, m=r, h=h, w=w, panels=[pa[s:s + r] for pa in panels],
+                block=block, m_valid=_chunk_valid(m_valid, s, r),
+                debug_steps=debug_steps, interpret=interpret, chunk_rows=r))
+        outs = [p[0] if debug_steps else p for p in parts]
+        stacked = [_stack_rows([o[p][:r] for o, (_, r)
+                                in zip(outs, _row_spans(m, rows))],
+                               mb * bm) for p in range(nph)]
+        if debug_steps:
+            return stacked, sum(p[1] for p in parts)
+        return stacked
 
     # ---- pack (dynamic_update_slice only: the chained path must emit no
     # concatenate primitives — the traced launch counter counts them) ----
@@ -2220,11 +2424,13 @@ def _gmm_experts_kernel(tab_ref, dyn_ref, x_ref, wh_ref, wo_ref, sw_ref,
     @pl.when(phase < 2)
     def _h_step():
         acc_ref[...] += jnp.dot(x_ref[...], wh_ref[...],
+                                precision=mxu_precision(dt),
                                 preferred_element_type=jnp.float32)
 
     @pl.when(phase == 2)
     def _y_step():
         acc_ref[...] += jnp.dot(hpost_s[hj].astype(dt), wo_ref[...],
+                                precision=mxu_precision(dt),
                                 preferred_element_type=jnp.float32)
 
     @pl.when((phase == 0) & last)
@@ -2277,7 +2483,7 @@ def _unpack_rows(tiles, mbs: int, bm: int, nb: int, d: int):
 
 def grouped_matmul_experts(xp, swp, w_in, w_out, w_gate, counts, *,
                            activation: str = "silu", train: bool = False,
-                           bm: int | None = None, interpret=True):
+                           bm: int | None = None, interpret: bool = False):
     """ONE launch over E expert chains with per-expert ragged M.
 
     xp     (MBS*bm, D)  tokens packed into block-aligned per-expert
@@ -2444,6 +2650,7 @@ def _gmm_experts_bwd_kernel(tab_ref, dyn_ref, x_ref, dy_ref, wht_ref,
     @pl.when(phase == 0)
     def _a_step():
         acc_ref[...] += jnp.dot(dy_ref[...], wot_ref[...],
+                                precision=mxu_precision(dt),
                                 preferred_element_type=jnp.float32)
 
     fb = dpan_s.shape[0] // (2 if gated else 1)
@@ -2473,6 +2680,7 @@ def _gmm_experts_bwd_kernel(tab_ref, dyn_ref, x_ref, dy_ref, wht_ref,
 
         dwo_acc[slot] += jax.lax.dot_general(
             hpost_s[pj].astype(dt), dy_ref[...], cdims,
+            precision=mxu_precision(dt),
             preferred_element_type=jnp.float32)
 
         @pl.when(lebl)
@@ -2482,6 +2690,7 @@ def _gmm_experts_bwd_kernel(tab_ref, dyn_ref, x_ref, dy_ref, wht_ref,
     @pl.when(phase == 2)
     def _c_step():
         acc_ref[...] += jnp.dot(dpan_s[pj].astype(dt), wht_ref[...],
+                                precision=mxu_precision(dt),
                                 preferred_element_type=jnp.float32)
 
     @pl.when((phase == 2) & last)
@@ -2501,6 +2710,7 @@ def _gmm_experts_bwd_kernel(tab_ref, dyn_ref, x_ref, dy_ref, wht_ref,
 
         dwh_acc[slot] += jax.lax.dot_general(
             x_ref[...], dpan_s[pj].astype(dt), cdims,
+            precision=mxu_precision(dt),
             preferred_element_type=jnp.float32)
 
         @pl.when(lebl)
@@ -2519,7 +2729,7 @@ def _expert_wstack_t(w, d0p: int, d1p: int):
 
 def grouped_matmul_experts_bwd(xp, dyp, w_in, w_out, w_gate, hinp, gatep,
                                counts, *, activation: str = "silu",
-                               bm: int, interpret=True):
+                               bm: int, interpret: bool = False):
     """ONE combined backward launch (dX + dW_in/dW_gate/dW_out) mirroring
     ``grouped_matmul_bwd``, over the per-expert ragged packing.
 

@@ -28,6 +28,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def mxu_precision(dtype):
+    """Contraction precision of a GEMM on the MXU, in a kernel or in XLA:
+    f32 operands take the full-f32 contraction — the TPU default is one
+    bf16 pass, which put the f32 googlenet's logits 5e-3 off the f32
+    reference on a v5e — and bf16 operands, exact in one pass, keep the
+    default."""
+    return jax.lax.Precision.HIGHEST \
+        if jnp.dtype(dtype) == jnp.float32 else None
+
+
 def _mm_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
     """Accumulating tiled matmul body shared by mxu128/large_tile."""
     k = pl.program_id(2)
@@ -37,7 +47,8 @@ def _mm_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        x_ref[...], y_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], y_ref[...], precision=mxu_precision(x_ref.dtype),
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(k == nk - 1)
@@ -79,7 +90,8 @@ def _ksplit_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        x_ref[...], y_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], y_ref[...], precision=mxu_precision(x_ref.dtype),
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(k == nk - 1)

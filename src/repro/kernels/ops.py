@@ -171,7 +171,8 @@ _branch_matmul_vjp.defvjp(_branch_matmul_fwd, _branch_matmul_bwd)
 # ---------------------------------------------------------------------------
 
 def grouped_matmul(xs, ws, bs=None, *, relu: bool = False, m_valid=None,
-                   interpret: bool | None = None):
+                   interpret: bool | None = None,
+                   chunk_rows: int | None = None):
     """G ragged branch GEMMs (M, K_g) @ (K_g, N_g) (+bias, +ReLU) in ONE
     kernel — see ``kernels/grouped_matmul.py``.
 
@@ -187,15 +188,21 @@ def grouped_matmul(xs, ws, bs=None, *, relu: bool = False, m_valid=None,
     rows at/past ``m_valid`` are padding and the epilogue stores zeros
     there.  The ragged path is INFERENCE-ONLY (a direct kernel call, no
     custom VJP: an integer row count has no meaningful cotangent and the
-    serving driver never differentiates)."""
+    serving driver never differentiates).
+
+    ``chunk_rows`` (the plan's ``ExecGroup.chunk_rows``) caps the rows
+    per launch so each launch's offset table fits SMEM; both directions
+    split at the same rows.  None sizes the chunks per launch."""
     interpret = default_interpret() if interpret is None else interpret
     if m_valid is not None:
         return list(_gmm.grouped_matmul(list(xs), list(ws),
                                         None if bs is None else list(bs),
                                         relu=relu, m_valid=m_valid,
-                                        interpret=interpret))
+                                        interpret=interpret,
+                                        chunk_rows=chunk_rows))
     return _grouped_vjp(tuple(xs), tuple(ws),
-                        None if bs is None else tuple(bs), relu, interpret)
+                        None if bs is None else tuple(bs), relu, interpret,
+                        chunk_rows)
 
 
 def grouped_matmul_dw(xs, dys, ys=None, *, interpret: bool | None = None):
@@ -206,18 +213,19 @@ def grouped_matmul_dw(xs, dys, ys=None, *, interpret: bool | None = None):
     return _gmm.grouped_matmul_dw(xs, dys, ys, interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _grouped_vjp(xs, ws, bs, relu, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped_vjp(xs, ws, bs, relu, interpret, chunk_rows):
     return tuple(_gmm.grouped_matmul(xs, ws, bs, relu=relu,
-                                     interpret=interpret))
+                                     interpret=interpret,
+                                     chunk_rows=chunk_rows))
 
 
-def _grouped_fwd(xs, ws, bs, relu, interpret):
-    ys = _grouped_vjp(xs, ws, bs, relu, interpret)
+def _grouped_fwd(xs, ws, bs, relu, interpret, chunk_rows):
+    ys = _grouped_vjp(xs, ws, bs, relu, interpret, chunk_rows)
     return ys, (xs, ws, bs, ys if relu else None)
 
 
-def _grouped_bwd(relu, interpret, res, gs):
+def _grouped_bwd(relu, interpret, chunk_rows, res, gs):
     xs, ws, bs, ys = res
     dys = [g.astype(x.dtype) for g, x in zip(gs, xs)]
     mask = list(ys) if relu else None
@@ -225,7 +233,8 @@ def _grouped_bwd(relu, interpret, res, gs):
     # two-phase offset table (was two grouped launches, with the dY and
     # mask stacks packed once per launch instead of once per call)
     dxs, dws, dbs = _gmm.grouped_matmul_bwd(xs, ws, dys, mask,
-                                            interpret=interpret)
+                                            interpret=interpret,
+                                            chunk_rows=chunk_rows)
     dws = tuple(dw.astype(w.dtype) for dw, w in zip(dws, ws))
     dbs = None if bs is None else tuple(
         db.astype(b.dtype) for db, b in zip(dbs, bs))
@@ -237,7 +246,8 @@ _grouped_vjp.defvjp(_grouped_fwd, _grouped_bwd)
 
 def grouped_matmul_concat(xs, ws, bs=None, *, offsets, total: int,
                           relu: bool = False, compact: bool = True,
-                          m_valid=None, interpret: bool | None = None):
+                          m_valid=None, interpret: bool | None = None,
+                          chunk_rows: int | None = None):
     """Fused epilogue-concat grouped GEMM: G ragged branches whose
     bias+ReLU epilogues write straight into the fork/join's (M, total)
     concat layout at per-branch column ``offsets`` — the join leaves the
@@ -251,39 +261,46 @@ def grouped_matmul_concat(xs, ws, bs=None, *, offsets, total: int,
     slices each branch's cotangent (and its ReLU mask) out of the joint
     buffer and emits ONE combined backward launch (masked dx + dw/db,
     ``grouped_matmul_bwd``).  ``m_valid`` makes the launch ragged-M
-    (inference-only direct kernel call — see ``grouped_matmul``)."""
+    (inference-only direct kernel call — see ``grouped_matmul``), and
+    ``chunk_rows`` splits it into SMEM-sized launches (as there)."""
     interpret = default_interpret() if interpret is None else interpret
     if m_valid is not None:
         return _gmm.grouped_matmul_concat(
             list(xs), list(ws), None if bs is None else list(bs),
             offsets=tuple(int(o) for o in offsets), total=int(total),
             relu=relu, compact=compact, m_valid=m_valid,
-            interpret=interpret)
+            interpret=interpret, chunk_rows=chunk_rows)
     return _concat_vjp(tuple(xs), tuple(ws),
                        None if bs is None else tuple(bs),
                        tuple(int(o) for o in offsets), int(total), relu,
-                       compact, interpret)
+                       compact, interpret, chunk_rows)
 
 
 def grouped_matmul_bwd(xs, ws, dys, ys=None, *,
-                       interpret: bool | None = None):
+                       interpret: bool | None = None,
+                       chunk_rows: int | None = None):
     """(dxs, dws, dbs) of a grouped branch GEMM in ONE combined launch
     (masked dx + dw/db over a concatenated two-phase offset table; dy is
     masked by y_g > 0 when ``ys`` is given) — see
     ``kernels/grouped_matmul.py``."""
     interpret = default_interpret() if interpret is None else interpret
-    return _gmm.grouped_matmul_bwd(xs, ws, dys, ys, interpret=interpret)
+    return _gmm.grouped_matmul_bwd(xs, ws, dys, ys, interpret=interpret,
+                                   chunk_rows=chunk_rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _concat_vjp(xs, ws, bs, offsets, total, relu, compact, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _concat_vjp(xs, ws, bs, offsets, total, relu, compact, interpret,
+                chunk_rows):
     return _gmm.grouped_matmul_concat(xs, ws, bs, offsets=offsets,
                                       total=total, relu=relu,
-                                      compact=compact, interpret=interpret)
+                                      compact=compact, interpret=interpret,
+                                      chunk_rows=chunk_rows)
 
 
-def _concat_fwd(xs, ws, bs, offsets, total, relu, compact, interpret):
-    y = _concat_vjp(xs, ws, bs, offsets, total, relu, compact, interpret)
+def _concat_fwd(xs, ws, bs, offsets, total, relu, compact, interpret,
+                chunk_rows):
+    y = _concat_vjp(xs, ws, bs, offsets, total, relu, compact, interpret,
+                    chunk_rows)
     return y, (xs, ws, bs, y if relu else None)
 
 
@@ -302,7 +319,8 @@ def _concat_offsets(xs, ws, offsets, compact):
     return offs
 
 
-def _concat_bwd(offsets, total, relu, compact, interpret, res, g):
+def _concat_bwd(offsets, total, relu, compact, interpret, chunk_rows, res,
+                g):
     xs, ws, bs, y = res
     offs = _concat_offsets(xs, ws, offsets, compact)
     dys = [g[:, off:off + w.shape[1]].astype(x.dtype)
@@ -310,7 +328,8 @@ def _concat_bwd(offsets, total, relu, compact, interpret, res, g):
     mask = [y[:, off:off + w.shape[1]]
             for off, w in zip(offs, ws)] if relu else None
     dxs, dws, dbs = _gmm.grouped_matmul_bwd(xs, ws, dys, mask,
-                                            interpret=interpret)
+                                            interpret=interpret,
+                                            chunk_rows=chunk_rows)
     dws = tuple(dw.astype(w.dtype) for dw, w in zip(dws, ws))
     dbs = None if bs is None else tuple(
         db.astype(b.dtype) for db, b in zip(dbs, bs))
@@ -325,7 +344,8 @@ _concat_vjp.defvjp(_concat_fwd, _concat_bwd)
 # ---------------------------------------------------------------------------
 
 def grouped_matmul_pooled(xs, ws, bs=None, *, relu: bool = False,
-                          m_valid=None, interpret: bool | None = None):
+                          m_valid=None, interpret: bool | None = None,
+                          chunk_rows: int | None = None):
     """Grouped ragged branch GEMMs with each pooled branch's maxpool
     computed IN-KERNEL as a pre-GEMM stage (``xs[g]`` a sequence of
     ``pool_tap_views`` tap arrays) — ONE launch covers pooling, GEMMs and
@@ -338,21 +358,25 @@ def grouped_matmul_pooled(xs, ws, bs=None, *, relu: bool = False,
     (elementwise, like the ReLU cotangent mask folded into the packing —
     gradients match the XLA ``reduce_window`` oracle bit-for-bit,
     tie-breaking included).  ``m_valid`` makes the launch ragged-M
-    (inference-only direct kernel call — see ``grouped_matmul``)."""
+    (inference-only direct kernel call) and ``chunk_rows`` splits it into
+    SMEM-sized launches — both as in ``grouped_matmul``."""
     interpret = default_interpret() if interpret is None else interpret
     if m_valid is not None:
         return list(_gmm.grouped_matmul_pooled(
             list(xs), list(ws), None if bs is None else list(bs),
-            relu=relu, m_valid=m_valid, interpret=interpret))
+            relu=relu, m_valid=m_valid, interpret=interpret,
+            chunk_rows=chunk_rows))
     xs_t = tuple(tuple(x) if isinstance(x, (list, tuple)) else x
                  for x in xs)
     return _pooled_vjp(xs_t, tuple(ws),
-                       None if bs is None else tuple(bs), relu, interpret)
+                       None if bs is None else tuple(bs), relu, interpret,
+                       chunk_rows)
 
 
 def grouped_matmul_pooled_concat(xs, ws, bs=None, *, offsets, total: int,
                                  relu: bool = False, compact: bool = True,
-                                 m_valid=None, interpret: bool | None = None):
+                                 m_valid=None, interpret: bool | None = None,
+                                 chunk_rows: int | None = None):
     """The fused epilogue-concat grouped GEMM with the in-kernel pool
     stage: pooling + GEMMs + bias/ReLU + the join assembly in ONE launch
     (``kernels/grouped_matmul.py::grouped_matmul_pooled_concat``).  Same
@@ -360,21 +384,22 @@ def grouped_matmul_pooled_concat(xs, ws, bs=None, *, offsets, total: int,
     ``grouped_matmul_concat``; the custom VJP slices the joint cotangent
     and emits ONE combined backward launch, scattering pooled branches'
     cotangents through their argmax masks in its unpacking.  ``m_valid``
-    makes the launch ragged-M (inference-only direct kernel call — see
-    ``grouped_matmul``)."""
+    makes the launch ragged-M (inference-only direct kernel call) and
+    ``chunk_rows`` splits it into SMEM-sized launches — both as in
+    ``grouped_matmul``."""
     interpret = default_interpret() if interpret is None else interpret
     if m_valid is not None:
         return _gmm.grouped_matmul_pooled_concat(
             list(xs), list(ws), None if bs is None else list(bs),
             offsets=tuple(int(o) for o in offsets), total=int(total),
             relu=relu, compact=compact, m_valid=m_valid,
-            interpret=interpret)
+            interpret=interpret, chunk_rows=chunk_rows)
     xs_t = tuple(tuple(x) if isinstance(x, (list, tuple)) else x
                  for x in xs)
     return _pooled_concat_vjp(xs_t, tuple(ws),
                               None if bs is None else tuple(bs),
                               tuple(int(o) for o in offsets), int(total),
-                              relu, compact, interpret)
+                              relu, compact, interpret, chunk_rows)
 
 
 def _pooled_flatten(xs):
@@ -402,24 +427,26 @@ def _pooled_scatter(xs, pooled, dxs):
     return tuple(outs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _pooled_vjp(xs, ws, bs, relu, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _pooled_vjp(xs, ws, bs, relu, interpret, chunk_rows):
     return tuple(_gmm.grouped_matmul_pooled(list(xs), ws, bs, relu=relu,
-                                            interpret=interpret))
+                                            interpret=interpret,
+                                            chunk_rows=chunk_rows))
 
 
-def _pooled_fwd(xs, ws, bs, relu, interpret):
-    ys = _pooled_vjp(xs, ws, bs, relu, interpret)
+def _pooled_fwd(xs, ws, bs, relu, interpret, chunk_rows):
+    ys = _pooled_vjp(xs, ws, bs, relu, interpret, chunk_rows)
     return ys, (xs, ws, bs, ys if relu else None)
 
 
-def _pooled_bwd(relu, interpret, res, gs):
+def _pooled_bwd(relu, interpret, chunk_rows, res, gs):
     xs, ws, bs, ys = res
     flat, pooled = _pooled_flatten(xs)
     dys = [g.astype(f.dtype) for g, f in zip(gs, flat)]
     mask = list(ys) if relu else None
     dxs, dws, dbs = _gmm.grouped_matmul_bwd(flat, ws, dys, mask,
-                                            interpret=interpret)
+                                            interpret=interpret,
+                                            chunk_rows=chunk_rows)
     dws = tuple(dw.astype(w.dtype) for dw, w in zip(dws, ws))
     dbs = None if bs is None else tuple(
         db.astype(b.dtype) for db, b in zip(dbs, bs))
@@ -429,22 +456,23 @@ def _pooled_bwd(relu, interpret, res, gs):
 _pooled_vjp.defvjp(_pooled_fwd, _pooled_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _pooled_concat_vjp(xs, ws, bs, offsets, total, relu, compact,
-                       interpret):
+                       interpret, chunk_rows):
     return _gmm.grouped_matmul_pooled_concat(
         list(xs), ws, bs, offsets=offsets, total=total, relu=relu,
-        compact=compact, interpret=interpret)
+        compact=compact, interpret=interpret, chunk_rows=chunk_rows)
 
 
 def _pooled_concat_fwd(xs, ws, bs, offsets, total, relu, compact,
-                       interpret):
+                       interpret, chunk_rows):
     y = _pooled_concat_vjp(xs, ws, bs, offsets, total, relu, compact,
-                           interpret)
+                           interpret, chunk_rows)
     return y, (xs, ws, bs, y if relu else None)
 
 
-def _pooled_concat_bwd(offsets, total, relu, compact, interpret, res, g):
+def _pooled_concat_bwd(offsets, total, relu, compact, interpret, chunk_rows,
+                       res, g):
     xs, ws, bs, y = res
     flat, pooled = _pooled_flatten(xs)
     offs = _concat_offsets(flat, ws, offsets, compact)
@@ -453,7 +481,8 @@ def _pooled_concat_bwd(offsets, total, relu, compact, interpret, res, g):
     mask = [y[:, off:off + w.shape[1]]
             for off, w in zip(offs, ws)] if relu else None
     dxs, dws, dbs = _gmm.grouped_matmul_bwd(flat, ws, dys, mask,
-                                            interpret=interpret)
+                                            interpret=interpret,
+                                            chunk_rows=chunk_rows)
     dws = tuple(dw.astype(w.dtype) for dw, w in zip(dws, ws))
     dbs = None if bs is None else tuple(
         db.astype(b.dtype) for db, b in zip(dbs, bs))
@@ -535,7 +564,8 @@ _fused_vjp.defvjp(_fused_fwd, _fused_bwd)
 
 def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
                            block: int = 128, m_valid=None,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           chunk_rows: int | None = None):
     """A CHAIN of grouped branch phases in ONE kernel — join-chaining
     (panel-source lhs descriptors), in-launch KxK ring convs and the
     fused bias+ReLU epilogue; see
@@ -555,12 +585,16 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
     the launch ragged-M and bypasses the VJP entirely — the serving
     path's masked chained launch, where dead M-blocks are skipped as
     no-op waves and live tail blocks store exact zeros.  Inference-only,
-    like every other ragged grouped-family wrapper."""
+    like every other ragged grouped-family wrapper.
+
+    ``chunk_rows`` (a multiple of the h*w image) runs the chain as one
+    launch per image-aligned chunk so each offset table fits SMEM; the
+    backward's per-phase launches split at the same rows."""
     interpret = default_interpret() if interpret is None else interpret
     if m_valid is not None:
         return list(_gmm.grouped_matmul_chained(
             phases, m=m, h=h, w=w, panels=list(panels), block=block,
-            m_valid=m_valid, interpret=interpret))
+            m_valid=m_valid, interpret=interpret, chunk_rows=chunk_rows))
     spec, xs_flat, ws, bss = [], [], [], []
     for phase in phases:
         ps = []
@@ -582,7 +616,7 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
         spec.append(tuple(ps))
     return list(_chained_vjp(tuple(xs_flat), tuple(ws), tuple(bss),
                              tuple(panels), tuple(spec), int(m), int(h),
-                             int(w), int(block), interpret))
+                             int(w), int(block), interpret, chunk_rows))
 
 
 def _chained_rebuild(xs_flat, ws, bss, spec):
@@ -627,21 +661,23 @@ def _add_block(buf, upd, r0: int, c0: int):
         buf, cur + upd.astype(buf.dtype), (r0, c0))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _chained_vjp(xs_flat, ws, bss, panels, spec, m, h, w, block, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _chained_vjp(xs_flat, ws, bss, panels, spec, m, h, w, block, interpret,
+                 chunk_rows):
     phases = _chained_rebuild(xs_flat, ws, bss, spec)
     return tuple(_gmm.grouped_matmul_chained(
         phases, m=m, h=h, w=w, panels=list(panels), block=block,
-        interpret=interpret))
+        interpret=interpret, chunk_rows=chunk_rows))
 
 
-def _chained_fwd(xs_flat, ws, bss, panels, spec, m, h, w, block, interpret):
+def _chained_fwd(xs_flat, ws, bss, panels, spec, m, h, w, block, interpret,
+                 chunk_rows):
     outs = _chained_vjp(xs_flat, ws, bss, panels, spec, m, h, w, block,
-                        interpret)
+                        interpret, chunk_rows)
     return outs, (xs_flat, ws, bss, panels, outs)
 
 
-def _chained_bwd(spec, m, h, w, block, interpret, res, gs):
+def _chained_bwd(spec, m, h, w, block, interpret, chunk_rows, res, gs):
     xs_flat, ws, bss, panels, outs = res
     blk = block
     # branch layout + ring col -> (producer phase, producer panel col block)
@@ -706,7 +742,8 @@ def _chained_bwd(spec, m, h, w, block, interpret, res, gs):
             wsl.append(ws[bi])
         # ONE combined launch for this phase's dx + dw + db
         dxs, dws_p, dbs_p = _gmm.grouped_matmul_bwd(
-            lhss, wsl, dys, masks, interpret=interpret)
+            lhss, wsl, dys, masks, interpret=interpret,
+            chunk_rows=chunk_rows)
         for k, bi in enumerate(idxs):
             _, cb, nbb, tag, meta, n, rw = flat[bi]
             dws[bi] = dws_p[k].astype(ws[bi].dtype)

@@ -27,7 +27,6 @@ import jax
 from repro.configs import ARCHS, SHAPES, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import input_specs
-from repro.roofline import xla_cost_analysis
 from repro.sharding import specs as SH
 
 LM_ARCHS = tuple(a for a in ARCHS if a != "googlenet")
@@ -133,7 +132,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             v = getattr(mem, k, None)
             if v is not None:
                 rec[k] = int(v)
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     if cost:
         rec["cost_flops"] = float(cost.get("flops", -1))
         rec["cost_bytes"] = float(cost.get("bytes accessed", -1))

@@ -6,22 +6,12 @@ import jax
 
 
 def make_mesh(shape, names):
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax wants explicit ``axis_types=(AxisType.Auto, ...)`` to keep the
-    mesh out of explicit-sharding mode; older releases (< 0.5) have neither
-    the kwarg nor ``jax.sharding.AxisType``.  Every mesh in this repo (and in
-    the test subprocesses) goes through here so version skew lives in one
-    place.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, names,
-                                 axis_types=(axis_type.Auto,) * len(names))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, names)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: keeps the mesh
+    out of explicit-sharding mode, so GSPMD propagates the shardings the
+    ``sharding/specs.py`` rules annotate.  Every mesh in this repo (and in
+    the test subprocesses) goes through here."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

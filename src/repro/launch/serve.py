@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_reduced
+from repro.launch import runtime
 from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.sharding import specs as SH
@@ -330,6 +331,8 @@ def main(argv=None):
     ap.add_argument("--max-images", type=int, default=4,
                     help="CNN path: max images per request/co-batch")
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
+    print(f"[serve] {runtime.device_line()}", flush=True)
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if getattr(cfg, "family", "") == "cnn":

@@ -37,6 +37,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_reduced
 from repro.data import Pipeline, SyntheticImages, SyntheticLM
+from repro.launch import runtime
 from repro.launch import steps as ST
 from repro.launch.mesh import make_local_mesh
 from repro.models import cnn as CNN
@@ -44,7 +45,7 @@ from repro.models import transformer as T
 from repro.sharding import specs as SH
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -64,8 +65,20 @@ def main(argv=None):
                     help="CNN-family execution plan: lower the schedule to "
                          "core/plan.py ExecGroups (concurrent), keep it "
                          "serial, or bypass planning (none)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    runtime.enable_compile_cache()
+    print(f"[train] {runtime.device_line()}", flush=True)
+    run(args)
+    return 0
+
+
+def run(args) -> list[float]:
+    """Train for ``args.steps`` steps; returns the per-step losses (each
+    taken before that step's update)."""
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_local_mesh()
     print(f"[train] {cfg.name}: N={cfg.param_count()/1e6:.2f}M params, "
@@ -108,8 +121,11 @@ def main(argv=None):
             plan, _ = CNN.plan_cnn(cfg, args.batch,
                                    concurrent=args.plan == "concurrent",
                                    train=True)
+            chunked = [(g.ops[0], g.chunks) for g in plan.groups
+                       if g.chunks > 1]
             print(f"[train] plan: modes={plan.mode_counts()} "
-                  f"modeled_makespan={plan.makespan * 1e3:.3f} ms")
+                  f"modeled_makespan={plan.makespan * 1e3:.3f} ms "
+                  f"smem_chunked={chunked}")
             bwd = plan.context.get("backward")
             if bwd is not None:
                 print(f"[train] backward plan: modes={bwd.mode_counts()} "
@@ -153,14 +169,14 @@ def main(argv=None):
             if stop["now"]:
                 print("[train] SIGTERM -> checkpoint + exit")
                 save(step + 1)
-                return 0
+                return losses
     if mgr:
         save(args.steps)
     first = np.mean(losses[:10]) if len(losses) >= 10 else losses[0]
     last = np.mean(losses[-10:])
     print(f"[train] done. loss {first:.4f} -> {last:.4f} "
           f"({'improved' if last < first else 'NOT improved'})")
-    return 0
+    return losses
 
 
 if __name__ == "__main__":

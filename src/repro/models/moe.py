@@ -62,7 +62,6 @@ def moe_apply(params, x, *, top_k: int, capacity_factor: float = 1.25,
     from repro.sharding import specs as SH
     mesh = getattr(SH._CTX, "mesh", None)
     if SH.perf_option("moe_local") and mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         dp = SH.logical_axes(mesh, "dp")
         dp_size = 1
@@ -78,10 +77,10 @@ def moe_apply(params, x, *, top_k: int, capacity_factor: float = 1.25,
                        for k, v in aux.items()}
                 return out, aux
 
-            fn = shard_map(local, mesh=mesh,
-                           in_specs=(P(), P(dp, None, None)),
-                           out_specs=(P(dp, None, None), P()),
-                           check_rep=False)
+            fn = jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(), P(dp, None, None)),
+                               out_specs=(P(dp, None, None), P()),
+                               check_vma=False)
             return fn(params, x)
 
     # moe_ep: expert-parallel local dispatch — experts stay sharded over the
@@ -94,7 +93,6 @@ def moe_apply(params, x, *, top_k: int, capacity_factor: float = 1.25,
             and "model" in mesh.axis_names \
             and e_total % mesh.shape["model"] == 0 \
             and "shared" not in params:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         dp = SH.logical_axes(mesh, "dp")
         dp_size = 1
@@ -120,10 +118,10 @@ def moe_apply(params, x, *, top_k: int, capacity_factor: float = 1.25,
                      "w_out": P("model", None, None)}
             if "w_gate" in params:
                 pspec["w_gate"] = P("model", None, None)
-            fn = shard_map(local_ep, mesh=mesh,
-                           in_specs=(pspec, P(dp, None, None)),
-                           out_specs=(P(dp, None, None), P()),
-                           check_rep=False)
+            fn = jax.shard_map(local_ep, mesh=mesh,
+                               in_specs=(pspec, P(dp, None, None)),
+                               out_specs=(P(dp, None, None), P()),
+                               check_vma=False)
             return fn(params, x)
 
     if impl == "grouped":

@@ -1,3 +1,3 @@
 from repro.roofline.analyze import (  # noqa: F401
-    analyze_hlo, roofline_terms, xla_cost_analysis, HloCost,
+    analyze_hlo, roofline_terms, HloCost,
 )

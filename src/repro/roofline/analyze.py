@@ -346,19 +346,6 @@ def analyze_hlo(text: str) -> HloCost:
     return HloModule(text).cost()
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of per-device-program dicts; newer
-    jax returns the dict directly.  Comparisons against the while-corrected
-    analyzer go through here.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
-
-
 def roofline_terms(cost: HloCost, *, chips_note: str = "per-chip") -> dict:
     """Three-term roofline (inputs are PER-CHIP quantities: post-SPMD HLO
     describes one device's program).

@@ -49,13 +49,12 @@ def _ring_allgather_matmul_local(x_local, w_local, *, axis: str, p: int):
 def matmul_allgather_x(x, w, mesh, axis: str = "model"):
     """x: (M, K) sharded on M over ``axis``; w: (K, N) sharded on N.
     Returns (M, N) sharded on N (replicated on M)."""
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_allgather_matmul_local, axis=axis,
                           p=mesh.shape[axis]),
         mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),
-        out_specs=P(None, axis), check_rep=False)
+        out_specs=P(None, axis), check_vma=False)
     return fn(x, w)
 
 
@@ -89,11 +88,10 @@ def _ring_reducescatter_matmul_local(x_local, w_local, *, axis: str,
 def matmul_reducescatter(x, w, mesh, axis: str = "model"):
     """x: (M, K) sharded on K over ``axis``; w: (K, N) sharded on K.
     Returns y = x @ w reduce-scattered over M: (M, N) with M sharded."""
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_reducescatter_matmul_local, axis=axis,
                           p=mesh.shape[axis]),
         mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
-        out_specs=P(axis, None), check_rep=False)
+        out_specs=P(axis, None), check_vma=False)
     return fn(x, w)

@@ -19,7 +19,7 @@ import pytest
 
 from repro.analysis import Finding, PlanVerificationError, verify_plan
 from repro.analysis import fallbacks, hazards, tables
-from repro.configs import get_reduced
+from repro.configs import get_config, get_reduced
 from repro.core import launch_count as lc
 from repro.models import cnn
 
@@ -228,6 +228,24 @@ def test_budget_fault_injection(fused_plan):
     assert out and all(f.checker == "budget" for f in out)
     with pytest.raises(PlanVerificationError):
         planlib._maybe_verify(plan, None, True)
+
+
+def test_smem_budget_fault_injection():
+    # the bucket-8 serving plan runs its stem chain as SMEM chunks: undo
+    # the chunking and planlint must see the 1.5 MiB offset table the
+    # chip's compiler refuses
+    import dataclasses
+    plan, _ = cnn.plan_cnn(get_config("googlenet"), 8, chain_modules=True)
+    assert verify_plan(plan) == []
+    i = next(i for i, g in enumerate(plan.groups) if g.ops[0] == "stem0")
+    stem = plan.groups[i]
+    assert stem.chunks > 1
+    plan.groups[i] = dataclasses.replace(stem, chunk_rows=0, chunks=1)
+    out = verify_plan(plan)
+    assert [f.checker for f in out] == ["budget"] and "SMEM" in out[0].detail
+    # a chunk count its chunk_rows does not give is a bounds finding
+    plan.groups[i] = dataclasses.replace(stem, chunks=stem.chunks + 1)
+    assert [f.checker for f in verify_plan(plan)] == ["bounds"]
 
 
 # ---------------------------------------------------------------------------

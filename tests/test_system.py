@@ -108,7 +108,7 @@ def test_dryrun_artifacts_complete():
 def test_hlo_analyzer_against_xla_on_unrolled():
     """The while-corrected analyzer agrees with XLA cost_analysis when
     there are no loops (exactness check)."""
-    from repro.roofline import analyze_hlo, xla_cost_analysis
+    from repro.roofline import analyze_hlo
 
     def unrolled(w, x):
         for i in range(4):
@@ -119,12 +119,12 @@ def test_hlo_analyzer_against_xla_on_unrolled():
     x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
     c = jax.jit(unrolled).lower(w, x).compile()
     mine = analyze_hlo(c.as_text()).flops
-    xla = xla_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(mine - xla) / xla < 0.05
 
 
 def test_hlo_analyzer_corrects_scan_undercount():
-    from repro.roofline import analyze_hlo, xla_cost_analysis
+    from repro.roofline import analyze_hlo
 
     def scanned(w, x):
         def body(x, wi):
@@ -135,6 +135,6 @@ def test_hlo_analyzer_corrects_scan_undercount():
     x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
     c = jax.jit(scanned).lower(w, x).compile()
     mine = analyze_hlo(c.as_text()).flops
-    xla = xla_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert mine > 7 * xla / 8 * 7      # ~8x the single-body count
     assert abs(mine - 8 * 2 * 64 * 128 * 128) / mine < 0.1
