@@ -29,11 +29,14 @@ Two serving paths share this module:
   tails zero-stored).  A warm request pays zero lowering, zero
   ``_plan_tiles*`` rebuilds and zero re-tracing — the driver warms every
   bucket once, resets the cache counters, and asserts the measured
-  stream runs at hit rate 1.0.  Latency is attributed per REQUEST
-  (queue wait + dispatch wall, completion of the LAST chunk for split
-  requests); p50/p99 are request-level percentiles with the sample
-  count reported alongside, and the raw dispatch-wall percentiles keep
-  their own ``dispatch_*`` keys (``serve_cnn_metrics`` — the numbers
+  stream runs at hit rate 1.0.  ``CNNServer`` is the one engine for
+  this path (split, admit, pack, run), with a profiler span around each
+  phase and around the transfer, launch and device wait of each
+  dispatch.  Latency is attributed per REQUEST (queue wait + dispatch
+  wall, completion of the LAST chunk for split requests); p50/p99 are
+  request-level percentiles with the sample count reported alongside,
+  and the raw dispatch-wall percentiles keep their own ``dispatch_*``
+  keys (``serve_cnn_metrics`` — the numbers
   ``benchmarks/run.py`` records into BENCH_plan.json).
 
       PYTHONPATH=src python -m repro.launch.serve --arch googlenet \\
@@ -58,8 +61,8 @@ from repro.sharding import specs as SH
 
 # importlib, not ``from repro.kernels import grouped_matmul``: the
 # package re-exports a FUNCTION of that name which shadows the submodule
-# attribute.  Module scope, NOT inside dispatch() — the import-machinery
-# lookup has no business riding the per-dispatch hot loop.
+# attribute.  Module scope, NOT inside CNNServer.run — the
+# import-machinery lookup has no business riding the per-dispatch hot loop.
 _gmm = importlib.import_module("repro.kernels.grouped_matmul")
 
 
@@ -114,6 +117,138 @@ def _admit(pending, max_images: int, ladder, rows_per_image: int, pmf):
     return batch, total
 
 
+class CNNServer:
+    """The stream-serving engine of a planned CNN: requests split into
+    chunks as they arrive (``split``), co-batches admitted from the queue
+    (``admit``), packed into the smallest bucket of the ladder that holds
+    them (``pack``) and run through the bucket's cached plan and jitted
+    executable (``run``).
+
+    Each phase is a host span on the profiler's clock
+    (``jax.profiler.TraceAnnotation``, inert without a profiler session):
+    ``serve.admit``, ``serve.pack`` and ``serve.dispatch``, which holds
+    ``serve.h2d`` (the packed images' transfer, until it is on the device),
+    ``serve.launch`` (the executable's call, which returns once the work is
+    enqueued) and ``serve.wait`` (until the logits are ready); in set-up,
+    ``serve.lower`` and ``serve.warm`` per bucket.
+
+    ``counters`` counts the stream since ``reset_counters``: dispatches
+    per bucket, valid and padded images, chunks admitted, the longest
+    queue met at admission, and the host-clock seconds spent in the
+    transfer, the launch and the wait.  ``setup`` keeps per bucket the
+    seconds of lowering and of the warm dispatch, and the packed input's
+    bytes on the host and on the device.
+    """
+
+    def __init__(self, cfg, params, max_images: int, *,
+                 chain_modules: bool = True, interpret=None):
+        from repro.core import cost_model as CM
+        from repro.core import plan_cache
+        from repro.launch.steps import make_cnn_serve_step
+
+        h, w, _c = cfg.img
+        self.cfg, self.params, self.max_images = cfg, params, max_images
+        self.rows_per_image = h * w
+        self.ladder = CM.serve_buckets(max_images, h * w)
+        self._pmf = CM.padded_m_factor
+        self._plan_cache = plan_cache
+        self.setup = {"lower_s": {}, "warm_s": {}, "input_host_bytes": {},
+                      "input_device_bytes": {}}
+        self.entries = {}
+        for b in self.ladder:
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("serve.lower", bucket=b):
+                entry = plan_cache.cached_cnn_plan(
+                    cfg, b, chain_modules=chain_modules)
+            self.setup["lower_s"][b] = time.perf_counter() - t0
+            if entry.executable is None:
+                entry.executable = jax.jit(make_cnn_serve_step(
+                    cfg, entry.plan, interpret=interpret))
+            self.entries[b] = entry
+        self.reset_counters()
+
+    def reset_counters(self) -> None:
+        self.counters = {"dispatches": {b: 0 for b in self.ladder},
+                         "valid_images": 0, "padded_images": 0,
+                         "chunks_admitted": 0, "pending_max": 0,
+                         "h2d_s": 0.0, "launch_s": 0.0, "wait_s": 0.0}
+
+    def split(self, rid: int, imgs, deadline: float):
+        return _split_request(rid, imgs, deadline, self.max_images)
+
+    def admit(self, pending):
+        """The next co-batch from ``pending`` (mutated), by ``_admit``."""
+        n = len(pending)
+        with jax.profiler.TraceAnnotation("serve.admit", pending=n):
+            batch, total = _admit(pending, self.max_images, self.ladder,
+                                  self.rows_per_image, self._pmf)
+        self.counters["chunks_admitted"] += len(batch)
+        self.counters["pending_max"] = max(self.counters["pending_max"], n)
+        return batch, total
+
+    def pack(self, arrs):
+        """``arrs`` in order in a zero-filled float32 array of the smallest
+        bucket that holds them: (images, bucket, valid images)."""
+        with jax.profiler.TraceAnnotation("serve.pack"):
+            n = sum(r.shape[0] for r in arrs)
+            bucket = _bucket_for(n, self.ladder)
+            imgs = np.zeros((bucket,) + tuple(self.cfg.img), np.float32)
+            off = 0
+            for r in arrs:
+                imgs[off:off + r.shape[0]] = r
+                off += r.shape[0]
+        return imgs, bucket, n
+
+    def run(self, imgs, bucket: int, n: int):
+        """The logits of a packed bucket, ready on the device."""
+        entry = self.entries[bucket]
+        c = self.counters
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(
+                "serve.dispatch", seq=sum(c["dispatches"].values()),
+                bucket=bucket, valid=n):
+            with jax.profiler.TraceAnnotation("serve.h2d"):
+                x = jax.block_until_ready(jnp.asarray(imgs))
+            t1 = time.perf_counter()
+            # record which device offset tables this entry's executable
+            # touches and pin them to the entry (first dispatch only): the
+            # plan cache's LRU eviction unpins them, so table memory tracks
+            # LIVE entries, not everything ever traced
+            with _gmm._device_table.recording() as touched:
+                with jax.profiler.TraceAnnotation("serve.launch"):
+                    logits = entry.executable(self.params, x, jnp.int32(n))
+                t2 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("serve.wait"):
+                    jax.block_until_ready(logits)
+        t3 = time.perf_counter()
+        self._plan_cache.attach_tables(entry, touched)
+        # the phases on the host's clock as well, so that an untraced
+        # dispatch splits too: a traced one carries the profiler's cost
+        c["h2d_s"] += t1 - t0
+        c["launch_s"] += t2 - t1
+        c["wait_s"] += t3 - t2
+        c["dispatches"][bucket] += 1
+        c["valid_images"] += n
+        c["padded_images"] += bucket - n
+        return logits
+
+    def warm(self) -> None:
+        """One full dispatch per bucket: traces, compiles or loads each
+        bucket's executable and pins its offset tables.  Leaves the
+        counters to the caller to reset."""
+        h, w, c = self.cfg.img
+        for b in self.ladder:
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("serve.warm", bucket=b):
+                imgs, bucket, n = self.pack([np.zeros((b, h, w, c),
+                                                      np.float32)])
+                self.run(imgs, bucket, n)
+            self.setup["warm_s"][b] = time.perf_counter() - t0
+            self.setup["input_host_bytes"][b] = imgs.nbytes
+            self.setup["input_device_bytes"][b] = \
+                jnp.asarray(imgs).on_device_size_in_bytes()
+
+
 def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
                       seed: int = 0, chain_modules: bool = True,
                       interpret=None) -> dict:
@@ -121,12 +256,12 @@ def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
 
     Synthetic seeded request stream: each request carries
     1..max_images+1 images (the +1 deliberately exercises the oversized
-    path) and a deadline drawn from the same rng.  Requests split into
-    <= max_images chunks, co-batches form by EDF-anchored
-    padded-M-factor packing (``_admit``), and each dispatch rides the
-    bucket's cached plan.  Warmup dispatches one batch per ladder bucket
-    (populating plan cache, device offset tables and jit traces), then
-    counters reset and the measured stream must be all cache hits.
+    path) and a deadline drawn from the same rng; every request is
+    submitted at once and served through one ``CNNServer``.  Warmup
+    dispatches one batch per ladder bucket (populating plan cache, device
+    offset tables and jit traces), then counters reset and the measured
+    stream must re-lower nothing: the cache still holds every bucket's
+    entry afterwards.
 
     Latency is per REQUEST: completion of its last chunk minus
     submission, i.e. queue wait + dispatch wall.  ``p50_ms``/``p99_ms``
@@ -135,43 +270,13 @@ def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
     """
     from repro.core import cost_model as CM
     from repro.core import plan_cache
-    from repro.launch.steps import make_cnn_serve_step
     from repro.models import cnn as CNN
 
     h, w, c = cfg.img
-    ladder = CM.serve_buckets(max_images, h * w)
     rng = np.random.default_rng(seed)
     params = CNN.init_params(cfg, jax.random.PRNGKey(seed))
-
-    def executable_for(bucket: int):
-        entry = plan_cache.cached_cnn_plan(cfg, bucket,
-                                           chain_modules=chain_modules)
-        if entry.executable is None:
-            step = make_cnn_serve_step(cfg, entry.plan, interpret=interpret)
-            entry.executable = jax.jit(step)
-        return entry
-
-    def dispatch(arrs):
-        n = sum(r.shape[0] for r in arrs)
-        bucket = _bucket_for(n, ladder)
-        entry = executable_for(bucket)
-        imgs = np.zeros((bucket, h, w, c), np.float32)
-        off = 0
-        for r in arrs:
-            imgs[off:off + r.shape[0]] = r
-            off += r.shape[0]
-        t0 = time.perf_counter()
-        # record which device offset tables this entry's executable
-        # touches and pin them to the entry (first dispatch only): the
-        # plan cache's LRU eviction unpins them, so table memory tracks
-        # LIVE entries, not everything ever traced
-        with _gmm._device_table.recording() as touched:
-            logits = entry.executable(params, jnp.asarray(imgs),
-                                      jnp.int32(n))
-            jax.block_until_ready(logits)
-        plan_cache.attach_tables(entry, touched)
-        lat = time.perf_counter() - t0
-        return logits, lat, bucket, n
+    engine = CNNServer(cfg, params, max_images,
+                       chain_modules=chain_modules, interpret=interpret)
 
     # request stream: image counts in [1, max_images + 1] — the +1 makes
     # oversized requests (must split, never truncate) part of every run
@@ -180,14 +285,13 @@ def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
     requests = [rng.normal(size=(int(s), h, w, c)).astype(np.float32)
                 for s in sizes]
 
-    # warmup: one dispatch per bucket — populates every cache layer
-    for b in ladder:
-        dispatch([np.zeros((b, h, w, c), np.float32)])
+    engine.warm()
     plan_cache.reset()          # counters only; entries stay warm
+    engine.reset_counters()
 
     pending = []
     for rid, (r, dl) in enumerate(zip(requests, deadlines)):
-        pending.extend(_split_request(rid, r, float(dl), max_images))
+        pending.extend(engine.split(rid, r, float(dl)))
     chunks_left = {rid: sum(1 for c_ in pending if c_["rid"] == rid)
                    for rid in range(num_requests)}
     submitted_images = int(sum(sizes))
@@ -197,11 +301,12 @@ def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
     served_images = 0
     t_start = time.perf_counter()
     while pending:
-        batch, total = _admit(pending, max_images, ladder, h * w,
-                              CM.padded_m_factor)
-        _, lat, bucket, n = dispatch([c_["imgs"] for c_ in batch])
+        batch, _total = engine.admit(pending)
+        imgs, bucket, n = engine.pack([c_["imgs"] for c_ in batch])
+        t0 = time.perf_counter()
+        engine.run(imgs, bucket, n)
         t_end = time.perf_counter()
-        dispatch_s.append(lat)
+        dispatch_s.append(t_end - t0)
         served_images += n
         waste.append(CM.padded_m_factor(n * h * w, bucket * h * w))
         for c_ in batch:
@@ -212,15 +317,21 @@ def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
 
     assert len(done_at) == num_requests and served_images == \
         submitted_images, "a submitted image never reached a launch"
+    # the stream ran on the entries the engine resolved at its start; the
+    # cache must still hold each one (one hit per bucket), none re-lowered
+    # or evicted
+    held = [plan_cache.cached_cnn_plan(cfg, b, chain_modules=chain_modules)
+            is e for b, e in engine.entries.items()]
     stats = plan_cache.stats()
-    assert stats["misses"] == 0 and stats["hit_rate"] == 1.0, (
-        f"warm serving path re-lowered a plan: {stats}")
+    assert all(held) and stats["misses"] == 0 \
+        and stats["hit_rate"] == 1.0, (
+            f"warm serving path re-lowered a plan: {stats}")
     req_ms = np.asarray([done_at[r] - t_start
                          for r in range(num_requests)]) * 1e3
     disp_ms = np.asarray(dispatch_s) * 1e3
     return {
         "arch": cfg.name,
-        "buckets": ladder,
+        "buckets": engine.ladder,
         "requests": int(num_requests),
         "dispatches": len(dispatch_s),
         "images": int(served_images),
@@ -239,10 +350,8 @@ def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,
         # per-ladder planlint coverage: a bucket's entry is verified when
         # its lowering ran analysis.verify_plan with zero findings
         # (pytest / REPRO_PLANLINT=1 — see plan._verify_requested)
-        "plans_verified": sum(
-            1 for b in ladder
-            if plan_cache.cached_cnn_plan(
-                cfg, b, chain_modules=chain_modules).verified),
+        "plans_verified": sum(1 for e in engine.entries.values()
+                              if e.verified),
     }
 
 
