@@ -28,8 +28,9 @@ The accounting, in one place:
                    the forward's.
   chained          ``cost_model.chained_profiles`` workspace (ring
                    consumers drop their patch buffer) plus the launch's
-                   ring scratch: 3 wave slots per ring column, the
-                   (3*bm, blk) shift window and the f32 accumulator.
+                   ring scratch: 3 wave slots and a (3*bm, blk) shift
+                   window per ring column, the rows' (h, w) and the f32
+                   accumulator.
   SMEM             every grouped-family launch prefetches its offset
                    table (one column per grid step, linear in M) into
                    the chip's SMEM (``cost_model.SMEM_PREFETCH_BYTES``).
@@ -114,9 +115,11 @@ def group_footprint(graph, names, algorithms, *, pools=(),
 def chained_footprint(graph, phases, ring, *, block: int = 128) -> Footprint:
     """The static footprint of one chained launch: chained-priced GEMM
     workspace (ring consumers' lhs never exists outside VMEM) plus the
-    VMEM ring scratch — 3 wave slots per ring column over every consumed
-    producer's K blocks, the (3*bm, blk) shift window and the f32
-    accumulator."""
+    VMEM ring scratch — per ring column over every consumed producer's K
+    blocks 3 wave slots and a (3*bm, blk) shift window; with any ring, the
+    int32 (h, w) of a block's rows (2 blocks) and the coordinate operand
+    (w + bm rows, at most 2 blocks: a tap's halo is at most bm) — and the
+    f32 accumulator."""
     ops = [graph.ops[n] for ph in phases for n in ph]
     profs = cm.chained_profiles(ops, ring)
     allnames = {m for ph in phases for m in ph}
@@ -127,7 +130,8 @@ def chained_footprint(graph, phases, ring, *, block: int = 128) -> Footprint:
                 consumed |= graph.pred[n] & allnames
     nring = sum(-(-graph.ops[n].p["k"] // block) for n in consumed)
     eb = max(op.dtype_bytes for op in ops)
-    ring_vmem = (3 * nring + 3) * block * block * eb + block * block * 4
+    ring_vmem = (6 * nring * eb + (1 + (4 if nring else 0)) * 4) \
+        * block * block
     return Footprint(sum(p.workspace_bytes for p in profs),
                      sum(p.vmem_bytes for p in profs) + ring_vmem)
 

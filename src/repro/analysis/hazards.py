@@ -5,17 +5,21 @@ The chained kernel (``grouped_matmul_chained``) runs its phases in a
 lag-1 wave: wave ``w`` executes phase ``p``'s M-block ``i = w - p``, so
 when a phase-``p+1`` consumer runs block ``i`` the producer phase has
 already stored blocks ``0..i+1`` — block ``i+1`` lands EARLIER in the
-same wave (phases ascend within a wave).  The kernel banks on that: a
-ring read assembles producer blocks ``i-1 / i / i+1`` from a 3-slot
-VMEM ring (slot = block mod 3) and slices the halo-shifted row window
-out of them.  Nothing at runtime checks the bank holds — these checkers
-prove it statically from the offset table alone:
+same wave (phases ascend within a wave).  The kernel banks on that: the
+first ring read of a (phase, block) for a ring column copies producer
+blocks ``i-1 / i / i+1`` from a 3-slot VMEM ring (slot = block mod 3)
+into that column's window, and every tap of the (phase, block) slices
+the halo-shifted rows out of the window.  Nothing at runtime checks the
+bank holds — these checkers prove it statically from the offset table
+alone:
 
   ``check_chained_schedule``  walks the table in execution order,
       tracking which M-block each (slot, ring column) pair last
-      received; every ring read must find exactly the block the slice
-      touches (mid always; lo when the halo shifts backward; hi when it
-      shifts forward), every tap must satisfy ``delta == dh*W + dw``
+      received and which blocks each column's window took at its
+      build; every ring read must find in the window exactly the block
+      the slice touches (mid always; lo when the halo shifts backward;
+      hi when it shifts forward), every tap must satisfy
+      ``delta == dh*W + dw``
       and ``|delta| <= bm`` (rows the shift pushes past a resident
       block are exactly the rows the border mask zeroes — the algebra
       is in the function docstring), and every ring column index must
@@ -40,7 +44,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.analysis.tables import (CH_DELTA, CH_DH, CH_DW, CH_I, CH_LAST,
-                                   CH_PH, CH_RC, CH_ROWS, CH_RWC, CH_SRC,
+                                   CH_PH, CH_RC, CH_RWC, CH_SRC,
                                    ch_mrow_row)
 
 
@@ -48,7 +52,9 @@ def check_chained_schedule(tab, m_blocks, nph, *, h, w, bm, nring):
     """Happens-before + geometry check on a chained offset table.
 
     Ring-read soundness: a read of producer block ``b`` is safe when the
-    slot ``b % 3`` last received exactly block ``b`` at an earlier step.
+    slot ``b % 3`` had last received exactly block ``b`` when the ring
+    column's window was built — the first ring read of the step's
+    (phase, block) for that column, ``tables.chained_step_counts``.
     The border mask covers the rest: a window row ``r`` (global output
     row) reads producer row ``r + delta`` with ``delta = dh*W + dw``;
     when the tap is unmasked (``0 <= r//W%H + dh < H`` and
@@ -63,11 +69,13 @@ def check_chained_schedule(tab, m_blocks, nph, *, h, w, bm, nring):
     out = []
     fam = "chained-schedule"
     tab = np.asarray(tab)
-    if tab.ndim != 2 or tab.shape[0] < CH_ROWS + 2 * nph:
+    if tab.ndim != 2 or tab.shape[0] <= ch_mrow_row(nph):
         out.append(("hazard", f"{fam}: table has {tab.shape[0] if tab.ndim == 2 else 0} "
-                              f"rows, want >= {CH_ROWS + 2 * nph}"))
+                              f"rows, want > {ch_mrow_row(nph)}"))
         return out
     ring: dict[tuple[int, int], int] = {}   # (slot, ring col) -> block
+    # ring col -> ((phase, block) slot it was built for, slot -> block)
+    wins: dict[int, tuple[int, dict]] = {}
     for t in range(tab.shape[1]):
         i = int(tab[CH_I, t])
         if not 0 <= i < m_blocks:
@@ -101,18 +109,22 @@ def check_chained_schedule(tab, m_blocks, nph, *, h, w, bm, nring):
                 needs.append(i)            # mid slot
             if d > 0:
                 needs.append(i + 1)        # hi slot
+            key = int(tab[ch_mrow_row(nph), t])
+            if wins.get(rc, (None,))[0] != key:
+                wins[rc] = (key, {sl: ring.get((sl, rc)) for sl in range(3)})
+            held = wins[rc][1]
             for b in needs:
                 if not 0 <= b < m_blocks:
                     continue               # border-masked edge rows
-                got = ring.get((b % 3, rc))
+                got = held[b % 3]
                 if got != b:
                     out.append((
                         "hazard",
                         f"{fam}: step {t} (block {i}) reads producer "
-                        f"block {b} from ring column {rc}, but slot "
-                        f"{b % 3} holds "
-                        f"{'nothing' if got is None else f'block {got}'}"
-                        " — the wave schedule broke happens-before"))
+                        f"block {b} from ring column {rc}, but its window "
+                        f"took {'nothing' if got is None else f'block {got}'}"
+                        f" from slot {b % 3} — the wave schedule broke "
+                        "happens-before"))
         if int(tab[CH_LAST, t]) == 1:
             rwc = int(tab[CH_RWC, t])
             if rwc >= 0:

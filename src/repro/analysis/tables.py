@@ -70,7 +70,13 @@ DW_ROWS = 7
 BW_DYT, BW_ABT, BW_FIRST, BW_LAST, BW_OT, BW_DODB, BW_DW, BW_BJ = range(8)
 BW_ROWS = 8
 
-#: chained family — ``_plan_tiles_chained``; out-row helpers below
+#: chained family — ``_plan_tiles_chained``; out-row helpers below.
+#: ``CH_SRC`` is the step's lhs source (0 x tile, 2 ring window, 3 / 4
+#: panel A / B).  ``CH_XT`` is an x-tile slot and ``CH_PCA`` / ``CH_PCB``
+#: a panel tile, ``block * chained_panel_stride(spec) + col block``; a
+#: step that reads no such operand carries the index of the next step
+#: that does (the last one's past it), so the pipeline never fetches a
+#: tile no step reads.
 (CH_I, CH_XT, CH_WT, CH_BJ, CH_FIRST, CH_LAST, CH_PH, CH_SRC,
  CH_PCA, CH_PCB, CH_RC, CH_DELTA, CH_DH, CH_DW, CH_RWC) = range(15)
 CH_ROWS = 15
@@ -104,6 +110,34 @@ def ch_mrow_row(nph: int) -> int:
     block with ``mrow == 0`` is entirely past ``m_valid`` and the wave
     becomes a no-op guard (GEMM/ring/pool steps never execute)."""
     return CH_ROWS + 2 * nph
+
+
+def chained_panel_stride(spec) -> int:
+    """Column blocks per panel row-block in a chained table's panel tile
+    index: one more than the widest column block any panel branch of
+    ``spec`` reads."""
+    return 1 + max((cb for phase in spec for tag, src, _nbb, _rw in phase
+                    if tag == "panel" for _p, cb in src), default=0)
+
+
+def chained_step_counts(tab, nph: int) -> dict:
+    """What one chained launch does, read off its offset table: grid
+    steps by lhs source (``x``, ``ring``, ``panel``) and ``window_builds``,
+    the ring windows the kernel assembles.  The kernel builds ring column
+    ``rc``'s window on the first ring step of a (phase, block) that reads
+    ``rc``, and every later tap of that (phase, block) reuses it.  Counts
+    the dense launch: a ragged one skips its dead blocks' steps."""
+    tab = np.asarray(tab)
+    src = tab[CH_SRC]
+    built: dict[int, int] = {}
+    builds = 0
+    for t in np.nonzero(src == 2)[0]:
+        rc, key = int(tab[CH_RC, t]), int(tab[ch_mrow_row(nph), t])
+        if built.get(rc) != key:
+            built[rc] = key
+            builds += 1
+    return {"x": int((src == 0).sum()), "ring": int((src == 2).sum()),
+            "panel": int((src >= 3).sum()), "window_builds": builds}
 
 
 def smem_bytes(shape) -> int:
@@ -632,6 +666,7 @@ def expected_chained(m_blocks, spec):
     ``ch_mrow_row`` liveness-slot row."""
     nph = len(spec)
     nrows = CH_ROWS + 2 * nph + 1
+    pstride = chained_panel_stride(spec)
     info, xbase, wbase, bbase = [], 0, 0, 0
     for phase in spec:
         pinfo, ob = [], 0
@@ -669,7 +704,8 @@ def expected_chained(m_blocks, spec):
                         elif kt == "panel":
                             pidx, pcb = kd
                             c[CH_SRC] = 3 + pidx
-                            c[CH_PCA if pidx == 0 else CH_PCB] = pcb
+                            c[CH_PCA if pidx == 0 else CH_PCB] = \
+                                i * pstride + pcb
                         else:
                             d, dh, dw, rc = kd
                             c[CH_SRC] = 2
@@ -689,6 +725,16 @@ def expected_chained(m_blocks, spec):
             if c[CH_PH] == p and c[CH_LAST] == 1:
                 nxt = (c[nr], c[nc])
             c[nr], c[nc] = nxt
+    # operand stability: steps that read no x (panel A, panel B) tile
+    # carry the next reader's tile, the last reader's past it
+    for row, want in ((CH_XT, 0), (CH_PCA, 3), (CH_PCB, 4)):
+        readers = [c[row] for c in cols if c[CH_SRC] == want]
+        nxt = readers[-1] if readers else 0
+        for c in reversed(cols):
+            if c[CH_SRC] == want:
+                nxt = c[row]
+            else:
+                c[row] = nxt
     return np.array(cols, np.int32).T
 
 
@@ -720,6 +766,30 @@ def check_chained(tab, m_blocks, spec):
             out.append(("schema", f"{fam}: mrow slot row disagrees with "
                                   "phase*m_blocks + block — a ragged "
                                   "launch would read the wrong liveness"))
+        out += _check_idle_operands(tab, fam)
+    return out
+
+
+def _check_idle_operands(tab, fam):
+    """A step that reads no x (panel A, panel B) tile must hold the tile
+    the next step that reads one does, else the pipeline fetches a tile
+    no step reads.  Past the last reader it holds the last reader's."""
+    out = []
+    for row, want, what in ((CH_XT, 0, "x"), (CH_PCA, 3, "panel-A"),
+                            (CH_PCB, 4, "panel-B")):
+        readers = np.nonzero(tab[CH_SRC] == want)[0]
+        if not readers.size:
+            continue
+        nxt = np.searchsorted(readers, np.arange(tab.shape[1]))
+        carry = tab[row, readers[np.minimum(nxt, readers.size - 1)]]
+        bad = np.nonzero((tab[CH_SRC] != want) & (tab[row] != carry))[0]
+        if bad.size:
+            t = int(bad[0])
+            out.append(("schema", f"{fam}: step {t} reads no {what} tile "
+                                  f"but holds {int(tab[row, t])}, not the "
+                                  f"neighbouring reader's {int(carry[t])}"
+                                  " — the pipeline would fetch a tile no "
+                                  "step reads"))
     return out
 
 

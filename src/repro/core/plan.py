@@ -411,8 +411,8 @@ def _chain_budgets_ok(graph: OpGraph, phases: list[list[str]], ring, *,
     """C2 re-check on the chained launch: the HBM workspace of its
     chained-priced GEMM lowering (ring consumers drop their patch buffer —
     their lhs never exists outside VMEM) plus the launch's ring scratch
-    against the VMEM budget: 3 wave slots per ring column, the (3*bm, blk)
-    shift window and the f32 accumulator.  The footprint itself comes
+    against the VMEM budget: 3 wave slots and a (3*bm, blk) shift window
+    per ring column, and the f32 accumulator.  The footprint itself comes
     from ``analysis.budgets.chained_footprint``.  SMEM: a one-image chunk
     of the launch (and, ``train``, of its backward) must fit — chunking
     splits anything larger (``analysis.budgets.chunk_rows``)."""

@@ -110,7 +110,8 @@ from repro.analysis.tables import (
     EX_HJ, EX_OT, EX_RES,
     EB_BI, EB_DYT, EB_XT, EB_WHT, EB_WOT, EB_RES, EB_PH, EB_FIRST,
     EB_LAST, EB_PJ, EB_DXOT, EB_DWH, EB_DWO,
-    ch_out_i_row, ch_out_j_row, ch_mrow_row, smem_bytes)
+    ch_out_i_row, ch_out_j_row, ch_mrow_row, chained_panel_stride,
+    chained_step_counts, smem_bytes)
 from repro.kernels.matmul import mxu_precision
 
 
@@ -126,6 +127,23 @@ def _count_launch(name: str) -> None:
 
 def reset_launch_counts() -> None:
     KERNEL_LAUNCHES.clear()
+
+
+# What the chained launches traced inside ``chained_steps_recording()`` do,
+# summed: the launches, their grid steps by lhs source and their
+# ring-window builds, read off each launch's offset table
+# (``tables.chained_step_counts``).
+_CHAIN_RECORDERS: list[dict] = []
+
+
+@contextlib.contextmanager
+def chained_steps_recording():
+    rec = {"launches": 0, "x": 0, "ring": 0, "panel": 0, "window_builds": 0}
+    _CHAIN_RECORDERS.append(rec)
+    try:
+        yield rec
+    finally:
+        _CHAIN_RECORDERS.remove(rec)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -1717,8 +1735,10 @@ def grouped_matmul_flops(shapes, bm: int = 128, bn: int = 128,
 #   src=0  the packed X tile stack (im2col / pooled-fold lhs prepped outside),
 #   src=2  a VMEM ring holding the last 3 row-block panels a PRODUCER phase
 #          of the same launch wrote — a KxK conv consumes them as K^2
-#          shifted 1x1 tap-GEMMs with iota-decoded border masking, so the
-#          producer activation never touches HBM,
+#          shifted 1x1 tap-GEMMs with border masking, so the producer
+#          activation never touches HBM.  The three panels are copied into
+#          a per-ring-column window once per (phase, block), with the
+#          block's (h, w) row coordinates; every tap then slices it,
 #   src=3/4  a PANEL operand — the padded join buffer a PREVIOUS chained
 #          launch emitted, consumed in place via a per-branch lhs-source
 #          descriptor (panel id + column block) in the scalar-prefetch
@@ -1730,10 +1750,13 @@ def grouped_matmul_flops(shapes, bm: int = 128, bn: int = 128,
 # writes one output panel whose segments are its branches' padded column
 # slabs — the layout the NEXT launch's panel descriptors address.
 # The bias+ReLU epilogue is fused (chained branches must be relu convs).
+# Each grid step does only its own source's work: the kernel switches on
+# CH_SRC, and emits no code for a source the launch does not have.
 
 # table rows are the CH_* constants in ``analysis.tables`` (plus 2 per
 # phase via ch_out_i_row/ch_out_j_row: output row-block / col-block, kept
-# on the "slot of the next write at step >= t" stability rule)
+# on the "slot of the next write at step >= t" stability rule, which the
+# x and panel tile rows follow too)
 
 
 def _chain_ksteps(tag, src):
@@ -1759,6 +1782,7 @@ def _plan_tiles_chained(m_blocks: int, phases):
     bookkeeping, cached."""
     nph = len(phases)
     nrows = CH_ROWS + 2 * nph + 1
+    pstride = chained_panel_stride(phases)
     info = []
     xbase = wbase = bbase = 0
     for phase in phases:
@@ -1799,7 +1823,8 @@ def _plan_tiles_chained(m_blocks: int, phases):
                         elif kt == "panel":
                             pidx, cb = kd
                             c[CH_SRC] = 3 + pidx
-                            c[CH_PCA if pidx == 0 else CH_PCB] = cb
+                            c[CH_PCA if pidx == 0 else CH_PCB] = \
+                                i * pstride + cb
                         else:
                             d, dh, dw, rc = kd
                             c[CH_SRC] = 2
@@ -1824,78 +1849,113 @@ def _plan_tiles_chained(m_blocks: int, phases):
             if c[CH_PH] == p and c[CH_LAST] == 1:
                 nxt = (c[nr], c[nc])
             c[nr], c[nc] = nxt
+    # input stability, the same rule: a step that reads no x tile (panel
+    # A tile, panel B tile) holds the one the next reader reads, so the
+    # pipeline fetches only tiles some step reads
+    for row, src in ((CH_XT, 0), (CH_PCA, 3), (CH_PCB, 4)):
+        nxt = next((c[row] for c in reversed(cols) if c[CH_SRC] == src), 0)
+        for c in reversed(cols):
+            if c[CH_SRC] == src:
+                nxt = c[row]
+            else:
+                c[row] = nxt
     return np.array(cols, np.int32).T
 
 
+@functools.lru_cache(maxsize=512)
+def _chained_counts(m_blocks: int, phases):
+    return chained_step_counts(_plan_tiles_chained(m_blocks, phases),
+                               len(phases))
+
+
 def _gmm_chained_kernel(*args, nphases: int, npanels: int, bm: int,
-                        blk: int, ragged: bool = False,
-                        debug_steps: bool = False):
-    if ragged:
-        tab_ref, mrow_ref, dims_ref = args[0], args[1], args[2]
-        refs = args[3:]
-    else:
-        tab_ref, dims_ref = args[0], args[1]
-        refs = args[2:]
-    x_ref, w_ref, b_ref = refs[0], refs[1], refs[2]
-    p_refs = refs[3:3 + npanels]
-    out_refs = refs[3 + npanels:3 + npanels + nphases]
-    nout = 3 + npanels + nphases
-    cnt_ref = refs[nout] if debug_steps else None
-    acc_ref, ring_ref, win_ref = refs[nout + (1 if debug_steps else 0):]
+                        has_x: bool, nring: int, hwraps: int,
+                        ragged: bool = False, debug_steps: bool = False):
+    refs = iter(args)
+    tab_ref = next(refs)
+    mrow_ref = next(refs) if ragged else None
+    dims_ref, x_ref, w_ref, b_ref = (next(refs) for _ in range(4))
+    coord_ref = next(refs) if nring else None
+    p_refs = [next(refs) for _ in range(npanels)]
+    out_refs = [next(refs) for _ in range(nphases)]
+    cnt_ref = next(refs) if debug_steps else None
+    acc_ref = next(refs)
+    if nring:
+        ring_ref, win_ref, hw_ref, wkey_ref = refs
     t = pl.program_id(0)
     i = tab_ref[CH_I, t]
     src = tab_ref[CH_SRC, t]
-    hd = dims_ref[0]
-    wd = dims_ref[1]
+    # the (phase, block) slot: ragged liveness, and the key of the ring
+    # windows built for this (phase, block)
+    slot = tab_ref[ch_mrow_row(nphases), t]
     # per-phase liveness: this (phase, block)'s true row count.  mrow == 0
     # means the block is entirely past m_valid and the whole wave is a
-    # no-op guard — init, window assembly, GEMM, store and ring write all
+    # no-op guard — init, window build, GEMM, store and ring write all
     # skipped, never merely zeroed.
-    mrow = mrow_ref[tab_ref[ch_mrow_row(nphases), t]] if ragged else None
+    mrow = mrow_ref[slot] if ragged else None
     live = (mrow > 0) if ragged else None
 
-    if debug_steps:
-        @pl.when(t == 0)
-        def _cnt_init():
+    def _reset():
+        if debug_steps:
             cnt_ref[0, 0] = 0
+            cnt_ref[0, 1] = 0
+        for rc in range(nring):         # no window built yet
+            wkey_ref[rc] = -1
+    if debug_steps or nring:
+        pl.when(t == 0)(_reset)
 
-        def _cnt():
-            cnt_ref[0, 0] += 1
-        if ragged:
-            pl.when(live)(_cnt)
-        else:
-            _cnt()
+    def _acc(lhs):
+        acc_ref[...] += jnp.dot(lhs, w_ref[...],
+                                precision=mxu_precision(lhs.dtype),
+                                preferred_element_type=jnp.float32)
+
+    def _ring_step():
+        rc = tab_ref[CH_RC, t]
+        hd = dims_ref[0]
+        wd = dims_ref[1]
+
+        @pl.when(wkey_ref[rc] != slot)
+        def _build():
+            # producer row-block panels i-1, i, i+1 side by side, and the
+            # (h, w) of each of block i's rows: row r = i*bm + k sits at
+            # off + k in its image, off = i*bm mod h*w = h0*w + w0, and
+            # the coordinate operand holds (u // w, u % w) of u = w0 + k
+            wkey_ref[rc] = slot
+            win_ref[rc, pl.ds(0, bm), :] = ring_ref[(i + 2) % 3, rc]
+            win_ref[rc, pl.ds(bm, bm), :] = ring_ref[i % 3, rc]
+            win_ref[rc, pl.ds(2 * bm, bm), :] = ring_ref[(i + 1) % 3, rc]
+            off = jax.lax.rem(i * bm, hd * wd)
+            code = coord_ref[pl.ds(jax.lax.rem(off, wd), bm), :]
+            hh = jax.lax.div(off, wd) + (code >> 16)
+            for _ in range(hwraps):
+                hh = jnp.where(hh >= hd, hh - hd, hh)
+            hw_ref[0] = hh
+            hw_ref[1] = code & 0xFFFF
+            if debug_steps:
+                cnt_ref[0, 1] += 1
+
+        shifted = win_ref[rc, pl.ds(bm + tab_ref[CH_DELTA, t], bm), :]
+        dh = tab_ref[CH_DH, t]
+        dw = tab_ref[CH_DW, t]
+        hh = hw_ref[0]
+        ww = hw_ref[1]
+        valid = (hh >= -dh) & (hh < hd - dh) & (ww >= -dw) & (ww < wd - dw)
+        _acc(jnp.where(valid, shifted, jnp.zeros_like(shifted)))
 
     def _body():
+        if debug_steps:
+            cnt_ref[0, 0] += 1
+
         @pl.when(tab_ref[CH_FIRST, t] == 1)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        xop = x_ref[...]
-        # ring window: producer row-block panels i-1, i, i+1 assembled
-        # into a (3*bm, blk) scratch, then one dynamic-start shifted load
-        # + border mask
-        slo = (i + 2) % 3
-        smi = i % 3
-        shi = (i + 1) % 3
-        rc = tab_ref[CH_RC, t]
-        win_ref[pl.ds(0, bm), :] = ring_ref[slo, rc]
-        win_ref[pl.ds(bm, bm), :] = ring_ref[smi, rc]
-        win_ref[pl.ds(2 * bm, bm), :] = ring_ref[shi, rc]
-        shifted = win_ref[pl.ds(bm + tab_ref[CH_DELTA, t], bm), :]
-        r = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)[:, 0]
-        rem = r % (hd * wd)
-        hh = rem // wd + tab_ref[CH_DH, t]
-        ww = rem % wd + tab_ref[CH_DW, t]
-        valid = (hh >= 0) & (hh < hd) & (ww >= 0) & (ww < wd)
-        xop = jnp.where(src == 2,
-                        jnp.where(valid[:, None], shifted,
-                                  jnp.zeros_like(shifted)), xop)
+        if has_x:
+            pl.when(src == 0)(lambda: _acc(x_ref[...]))
+        if nring:
+            pl.when(src == 2)(_ring_step)
         for pi, p_ref in enumerate(p_refs):
-            xop = jnp.where(src == 3 + pi, p_ref[...], xop)
-        acc_ref[...] += jnp.dot(xop, w_ref[...],
-                                precision=mxu_precision(xop.dtype),
-                                preferred_element_type=jnp.float32)
+            pl.when(src == 3 + pi)(lambda p_ref=p_ref: _acc(p_ref[...]))
 
     def _store():
         bj = tab_ref[CH_BJ, t]
@@ -1905,7 +1965,7 @@ def _gmm_chained_kernel(*args, nphases: int, npanels: int, bm: int,
             # live tail block: exact zeros past the block's true rows, so
             # next-phase ring taps and next-launch panel descriptors read
             # clean producer slots
-            ri = jax.lax.broadcasted_iota(jnp.int32, (bm, blk), 0)
+            ri = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
             y = jnp.where(ri < mrow, y, 0.0)
         y = y.astype(out_refs[0].dtype)
         ph = tab_ref[CH_PH, t]
@@ -1914,11 +1974,12 @@ def _gmm_chained_kernel(*args, nphases: int, npanels: int, bm: int,
             def _(o_ref=o_ref):
                 o_ref[...] = y
 
-        rwc = tab_ref[CH_RWC, t]
+        if nring:
+            rwc = tab_ref[CH_RWC, t]
 
-        @pl.when(rwc >= 0)
-        def _ring():
-            ring_ref[i % 3, jnp.maximum(rwc, 0)] = y
+            @pl.when(rwc >= 0)
+            def _ring():
+                ring_ref[i % 3, jnp.maximum(rwc, 0)] = y
 
     last = tab_ref[CH_LAST, t] == 1
     if ragged:
@@ -1927,6 +1988,13 @@ def _gmm_chained_kernel(*args, nphases: int, npanels: int, bm: int,
     else:
         _body()
         pl.when(last)(_store)
+
+
+def _chain_coords(w: int, bm: int, blk: int):
+    """The ring border mask's coordinate operand: row u < w + bm holds
+    ``(u // w) << 16 | u % w`` in every lane."""
+    u = np.arange(w + bm, dtype=np.int32)
+    return np.repeat(((u // w) << 16 | u % w)[:, None], blk, axis=1)
 
 
 def _chain_dims(h: int, w: int):
@@ -2021,10 +2089,12 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
     table and traced executable.  Inference-only — the differentiable
     wrapper in ``kernels/ops.py`` rejects ragged chains from its VJP.
 
-    ``debug_steps=True`` additionally returns an executed-step counter
-    (the skip instrument): ``(panels, steps)`` where ``steps`` is a
-    (1, 1) i32 of grid steps that ran their body — dense launches count
-    every step, ragged launches only live-block steps.
+    ``debug_steps=True`` additionally returns the kernel's own counters
+    (the skip instrument): ``(panels, counts)`` where ``counts`` is a
+    (1, 2) i32, ``[0, 0]`` the grid steps that ran their body — dense
+    launches count every step, ragged launches only live-block steps —
+    and ``[0, 1]`` the ring windows built (``tables.chained_step_counts``
+    gives both for the dense launch).
 
     ``chunk_rows`` caps the rows per launch (SMEM chunking, a multiple of
     the h*w image; None sizes it from the table): the chain then runs as
@@ -2116,16 +2186,21 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
         assert pc % blk == 0, pa.shape
         pads.append(jnp.pad(pa, ((0, mp - pr), (0, 0))) if pr < mp
                     else pa[:mp])
-    nring = 1
-    for bs in flat_spec:
-        if bs[0] == "ring":
-            nring = max(nring, max(bs[1][1]) + 1)
-        if bs[3]:
-            nring = max(nring, max(bs[3]) + 1)
+    # the ring: none unless a branch reads it, else every column read or
+    # written
+    has_x = any(bs[0] == "x" for bs in flat_spec)
+    reads = [c for bs in flat_spec if bs[0] == "ring" for c in bs[1][1]]
+    writes = [c for bs in flat_spec for c in bs[3]]
+    assert reads or not writes, "a ring write with no ring read"
+    nring = 1 + max(reads + writes) if reads else 0
 
     _count_launch("grouped_matmul_chained")
     tab = _device_table(_plan_tiles_chained, mb, spec)
     dims = _device_table(_chain_dims, h, w)
+    for rec in _CHAIN_RECORDERS:
+        rec["launches"] += 1
+        for k, v in _chained_counts(mb, spec).items():
+            rec[k] += v
 
     ragged = m_valid is not None
     if ragged:
@@ -2150,11 +2225,27 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
         pl.BlockSpec(memory_space=pltpu.VMEM),
     ]
     ins = [xstack, wstack, bstack]
+    scratch = [pltpu.VMEM((bm, blk), jnp.float32)]
+    hwraps = 0
+    if nring:
+        # the border mask's coordinate operand; scratch: the ring, a
+        # window per ring column, the (h, w) of the rows of the block the
+        # windows hold, and the (phase, block) each window was built for.
+        # A row's h + u // w lies at most ``hwraps`` image heights past
+        # its image's.
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+        ins.append(_device_table(_chain_coords, w, bm, blk))
+        scratch += [pltpu.VMEM((3, nring, bm, blk), dtype),
+                    pltpu.VMEM((nring, 3 * bm, blk), dtype),
+                    pltpu.VMEM((2, bm, blk), jnp.int32),
+                    pltpu.SMEM((nring,), jnp.int32)]
+        hwraps = (h - 1 + (w + bm - 2) // w) // h
+    pstride = chained_panel_stride(spec)
     for pi, pa in enumerate(pads):
         row = CH_PCA if pi == 0 else CH_PCB
         in_specs.append(pl.BlockSpec(
             (bm, blk), _im(lambda t, tab, dims, row=row:
-                           (tab[CH_I, t], tab[row, t]))))
+                           (tab[row, t] // pstride, tab[row, t] % pstride))))
         ins.append(pa)
     ncbs = [sum(bs[2] for bs in pspec) for pspec in spec]
     out_specs = [
@@ -2167,24 +2258,21 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
                  for ncb in ncbs]
     if debug_steps:
         out_specs.append(pl.BlockSpec(
-            (1, 1), _im(lambda t, tab, dims: (0, 0))))
-        out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.int32))
+            (1, 2), _im(lambda t, tab, dims: (0, 0))))
+        out_shape.append(jax.ShapeDtypeStruct((1, 2), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if ragged else 2,
         grid=(tab.shape[1],),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((bm, blk), jnp.float32),
-            pltpu.VMEM((3, nring, bm, blk), dtype),
-            pltpu.VMEM((3 * bm, blk), dtype),
-        ],
+        scratch_shapes=scratch,
     )
     scalars = (tab, mrows, dims) if ragged else (tab, dims)
     outs = pl.pallas_call(
         functools.partial(_gmm_chained_kernel, nphases=nph,
-                          npanels=len(pads), bm=bm, blk=blk,
-                          ragged=ragged, debug_steps=debug_steps),
+                          npanels=len(pads), bm=bm, has_x=has_x,
+                          nring=nring, hwraps=hwraps, ragged=ragged,
+                          debug_steps=debug_steps),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,

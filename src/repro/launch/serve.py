@@ -136,8 +136,12 @@ class CNNServer:
     per bucket, valid and padded images, chunks admitted, the longest
     queue met at admission, and the host-clock seconds spent in the
     transfer, the launch and the wait.  ``setup`` keeps per bucket the
-    seconds of lowering and of the warm dispatch, and the packed input's
-    bytes on the host and on the device.
+    seconds of lowering and of the warm dispatch, the packed input's
+    bytes on the host and on the device, and what the bucket's chained
+    launches do, read off their offset tables as the step is traced:
+    ``chained_steps``, grid steps by lhs source (``x``, ``ring``,
+    ``panel``), and ``ring_window_builds`` (dense counts: a ragged
+    dispatch skips its dead blocks' steps).
     """
 
     def __init__(self, cfg, params, max_images: int, *,
@@ -153,17 +157,19 @@ class CNNServer:
         self._pmf = CM.padded_m_factor
         self._plan_cache = plan_cache
         self.setup = {"lower_s": {}, "warm_s": {}, "input_host_bytes": {},
-                      "input_device_bytes": {}}
-        self.entries = {}
+                      "input_device_bytes": {}, "chained_steps": {},
+                      "ring_window_builds": {}}
+        self.entries, self._steps = {}, {}
         for b in self.ladder:
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("serve.lower", bucket=b):
                 entry = plan_cache.cached_cnn_plan(
                     cfg, b, chain_modules=chain_modules)
             self.setup["lower_s"][b] = time.perf_counter() - t0
+            self._steps[b] = make_cnn_serve_step(cfg, entry.plan,
+                                                 interpret=interpret)
             if entry.executable is None:
-                entry.executable = jax.jit(make_cnn_serve_step(
-                    cfg, entry.plan, interpret=interpret))
+                entry.executable = jax.jit(self._steps[b])
             self.entries[b] = entry
         self.reset_counters()
 
@@ -239,7 +245,8 @@ class CNNServer:
         h, w, c = self.cfg.img
         for b in self.ladder:
             t0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation("serve.warm", bucket=b):
+            with jax.profiler.TraceAnnotation("serve.warm", bucket=b), \
+                    _gmm.chained_steps_recording() as rec:
                 imgs, bucket, n = self.pack([np.zeros((b, h, w, c),
                                                       np.float32)])
                 self.run(imgs, bucket, n)
@@ -247,6 +254,19 @@ class CNNServer:
             self.setup["input_host_bytes"][b] = imgs.nbytes
             self.setup["input_device_bytes"][b] = \
                 jnp.asarray(imgs).on_device_size_in_bytes()
+            if not rec["launches"] and any(
+                    g.mode == "grouped_chained"
+                    for g in self.entries[b].plan.groups):
+                # the executable was traced before this engine's warm-up:
+                # trace the step again, abstractly, to count
+                with _gmm.chained_steps_recording() as rec:
+                    jax.eval_shape(self._steps[b], self.params,
+                                   jax.ShapeDtypeStruct(imgs.shape,
+                                                        imgs.dtype),
+                                   jax.ShapeDtypeStruct((), jnp.int32))
+            self.setup["ring_window_builds"][b] = rec["window_builds"]
+            self.setup["chained_steps"][b] = {
+                k: rec[k] for k in ("x", "ring", "panel")}
 
 
 def serve_cnn_metrics(cfg, *, max_images: int = 4, num_requests: int = 12,

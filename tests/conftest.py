@@ -56,3 +56,50 @@ def tol_for(dtype):
     import jax.numpy as jnp
     return {"float32": dict(rtol=2e-3, atol=2e-3),
             "bfloat16": dict(rtol=5e-2, atol=5e-2)}[jnp.dtype(dtype).name]
+
+
+def mixed_chain(dtype="float32", b=2, h=14, w=14, key=0):
+    """A two-phase chained launch that draws on every lhs source:
+
+      phase 0  an x branch (2 output n-blocks, writing ring columns 0 and
+               1) and a panel branch reading both column blocks of a
+               previous launch's 256-wide panel;
+      phase 1  a 3x3 (2 n-blocks) and a 5x5 ring conv, each over ring
+               columns (0, 1), and a panel branch (column blocks 1, 0).
+
+    Returns (phases, x, panel, m): ``phases(x)`` builds the phase dicts
+    around an x operand, so a sliced-input run shares the weights; the
+    panel branches address ``panel`` by descriptor.
+    """
+    import jax.numpy as jnp
+    from repro.core import plan as planlib
+
+    dt = jnp.dtype(dtype)
+    ks = iter(jax.random.split(jax.random.PRNGKey(key), 12))
+
+    def rnd(shape, s):
+        return (jax.random.normal(next(ks), shape) * s).astype(dt)
+
+    m = b * h * w
+    panel = jnp.maximum(rnd((m, 256), 1.0), 0)
+    x = rnd((m, 96), 0.3)
+    wx, bx = rnd((96, 200), 0.3), rnd((200,), 1.0)
+    wp0, bp0 = rnd((256, 64), 0.3), rnd((64,), 1.0)
+    w3, b3 = rnd((200 * 9, 136), 0.05), rnd((136,), 1.0)
+    w5, b5 = rnd((200 * 25, 40), 0.03), rnd((40,), 1.0)
+    wp1, bp1 = rnd((256, 72), 0.3), rnd((72,), 1.0)
+
+    def phases(x):
+        return [
+            [{"n": 200, "w": planlib._pad_w_dense(wx, 128), "b": bx,
+              "src": ("x", [x]), "ring_write": (0, 1)},
+             {"n": 64, "w": planlib._pad_w_dense(wp0, 128), "b": bp0,
+              "src": ("panel", [(0, 0), (0, 1)]), "ring_write": None}],
+            [{"n": 136, "w": planlib._pack_w_ring(w3, 3, 3, 200, 2, 128),
+              "b": b3, "src": ("ring", 3, 3, (0, 1)), "ring_write": None},
+             {"n": 40, "w": planlib._pack_w_ring(w5, 5, 5, 200, 2, 128),
+              "b": b5, "src": ("ring", 5, 5, (0, 1)), "ring_write": None},
+             {"n": 72, "w": planlib._pad_w_dense(wp1, 128), "b": bp1,
+              "src": ("panel", [(0, 1), (0, 0)]), "ring_write": None}],
+        ]
+    return phases, x, panel, m

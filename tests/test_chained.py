@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import tol_for
+from conftest import mixed_chain, tol_for
+from repro.analysis import tables
 from repro.configs.googlenet import CONFIG as GOOGLENET, reduced
 from repro.core import launch_count as lc
 from repro.core import plan as planlib
@@ -112,6 +113,85 @@ def test_chained_kernel_gradients_match_reference():
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# kernel-level: one launch over every lhs source, and what each step does
+# ---------------------------------------------------------------------------
+
+def _mixed_outputs(phases, x, panel, m, m_valid=None):
+    return gmm.grouped_matmul_chained(phases(x), m=m, h=14, w=14,
+                                      panels=[panel], m_valid=m_valid,
+                                      debug_steps=True, interpret=True)
+
+
+@pytest.mark.parametrize("dtype,valid_images", [
+    ("float32", None), ("bfloat16", None), ("float32", 1),
+])
+def test_chained_kernel_mixed_sources_match_reference(dtype, valid_images):
+    """x, panel and ring steps in one launch (a 3x3 and a 5x5 over two
+    ring columns, two n-blocks), dense and ragged with dead tail blocks:
+    each step takes its own source's lhs, and the live rows match the
+    reference; a ragged launch zeroes its live tail block past m_valid."""
+    phases, x, panel, m = mixed_chain(dtype)
+    mv = None if valid_images is None else valid_images * 14 * 14
+    outs, _ = _mixed_outputs(phases, x, panel, m, mv)
+    refs = gmm.grouped_matmul_chained_ref(phases(x), m=m, h=14, w=14,
+                                          panels=[panel])
+    rows = m if mv is None else mv
+    tol = tol_for(dtype)
+    for got, want in zip(outs, refs):
+        got = np.asarray(got, np.float32)
+        np.testing.assert_allclose(got[:rows],
+                                   np.asarray(want, np.float32)[:rows],
+                                   **tol)
+        if mv is not None:
+            assert not got[mv:-(-mv // 128) * 128].any()
+
+
+@pytest.mark.parametrize("valid_images", [None, 1])
+def test_chained_window_builds_once_per_block_and_ring_column(
+        valid_images):
+    """The kernel builds a ring column's window once per (phase, block)
+    and every tap and n-block reuses it: 4 blocks x 2 ring columns, all
+    read by phase 1; a ragged launch with one live image (blocks 0 and 1
+    live) builds only the live blocks'.  The table's static count and the
+    kernel's own counter agree."""
+    phases, x, panel, m = mixed_chain()
+    mb = -(-m // 128)
+    mv = None if valid_images is None else valid_images * 14 * 14
+    _, cnt = _mixed_outputs(phases, x, panel, m, mv)
+    spec = gmm._chain_static(phases(x), 128, 128, 14)
+    counts = tables.chained_step_counts(gmm._plan_tiles_chained(mb, spec),
+                                        len(spec))
+    live = mb if mv is None else -(-mv // 128)
+    assert counts["window_builds"] == mb * 2
+    assert int(np.asarray(cnt)[0, 1]) == live * 2
+    per_block = {"x": 2, "panel": 2 + 2, "ring": (9 * 2) * 2 + 25 * 2}
+    assert {k: counts[k] for k in per_block} == \
+        {k: v * mb for k, v in per_block.items()}
+    if mv is None:
+        assert int(np.asarray(cnt)[0, 0]) == sum(per_block.values()) * mb
+
+
+def test_chained_launch_without_ring_builds_no_window():
+    """A launch with no ring branch has no ring, window or coordinate
+    operand, and counts no window build."""
+    phases, x, panel, m = mixed_chain()
+    p0 = [dict(br, ring_write=None) for br in phases(x)[0]]
+    outs, cnt = gmm.grouped_matmul_chained(
+        [p0], m=m, h=14, w=14, panels=[panel], debug_steps=True,
+        interpret=True)
+    ref = gmm.grouped_matmul_chained_ref([p0], m=m, h=14, w=14,
+                                         panels=[panel])
+    np.testing.assert_allclose(np.asarray(outs[0])[:m],
+                               np.asarray(ref[0])[:m], **tol_for("float32"))
+    spec = gmm._chain_static([p0], 128, 128, 14)
+    counts = tables.chained_step_counts(
+        gmm._plan_tiles_chained(-(-m // 128), spec), 1)
+    assert counts["ring"] == counts["window_builds"] == 0
+    assert int(np.asarray(cnt)[0, 1]) == 0
+    assert int(np.asarray(cnt)[0, 0]) == counts["x"] + counts["panel"]
 
 
 # ---------------------------------------------------------------------------

@@ -101,6 +101,43 @@ def test_chained_table_clean_and_mutants():
     assert _mutants_fire(tab, check, range(nrows)) == nrows
 
 
+def _mixed_spec():
+    """Phase 0: an x branch (ring columns 0, 1) and a panel branch over
+    panel A's column blocks 0 and 2; phase 1: a 3x3 ring conv over both
+    ring columns and a panel branch over panel B's column block 1."""
+    taps = tuple((dh * 4 + dw, dh, dw)
+                 for dh in (-1, 0, 1) for dw in (-1, 0, 1))
+    return ((("x", 1, 2, (0, 1)), ("panel", ((0, 0), (0, 2)), 1, ())),
+            (("ring", (taps, (0, 1)), 1, ()), ("panel", ((1, 1),), 1, ())))
+
+
+@pytest.mark.parametrize("row,src", [
+    (tables.CH_XT, 0), (tables.CH_PCA, 3), (tables.CH_PCB, 4)])
+def test_chained_idle_steps_hold_the_neighbouring_readers_tile(row, src):
+    """A step that reads no x (panel A, panel B) tile holds the tile of
+    the next step that reads one, the last reader's past it — so the
+    pipeline fetches no tile that no step reads — and the verifier names
+    a step that does not."""
+    spec = _mixed_spec()
+    tab = np.asarray(gmm._plan_tiles_chained(3, spec))
+    assert tables.check_chained(tab, 3, spec) == []
+    readers = np.nonzero(tab[tables.CH_SRC] == src)[0]
+    assert readers.size
+    for t in range(tab.shape[1]):
+        later = readers[readers >= t]
+        r = later[0] if later.size else readers[-1]
+        assert tab[row, t] == tab[row, r], (t, r)
+    # a panel tile names its block: stride = widest column block + 1
+    if src == 3:
+        assert (tab[row, readers] // tables.chained_panel_stride(spec)
+                == tab[tables.CH_I, readers]).all()
+    idle = np.nonzero(tab[tables.CH_SRC] != src)[0]
+    bad = tab.copy()
+    bad[row, idle[0]] += 1
+    assert any("reads no" in msg
+               for _, msg in tables.check_chained(bad, 3, spec))
+
+
 def test_experts_tables_clean_and_mutants():
     tab = gmm._plan_tiles_experts(2, 1, 1, 1)
     check = lambda tb: tables.check_experts(tb, 2, 1, 1, 1)
@@ -132,6 +169,19 @@ def test_chained_schedule_order_violation():
     tab = np.array(gmm._plan_tiles_chained(2, _chained_spec()))[:, ::-1]
     out = _schedule(tab)
     assert any(kind == "hazard" for kind, _ in out)
+
+
+def test_chained_schedule_stale_window():
+    """The window of a (phase, block) is built at its first ring read and
+    reused: a producer write that lands after that build, though before
+    the taps that need it, is a hazard.  Block 1's producer steps moved
+    to just after block 0's first consumer tap (which needs blocks -1
+    and 0 only) leave every later tap reading a window without block 1."""
+    tab = np.array(gmm._plan_tiles_chained(2, _chained_spec()))
+    assert tab[tables.CH_SRC, 4] == 2 and tab[tables.CH_DELTA, 4] < 0
+    order = [0, 1, 4, 2, 3] + list(range(5, tab.shape[1]))
+    out = _schedule(tab[:, order])
+    assert any(kind == "hazard" and "window" in msg for kind, msg in out)
 
 
 def test_chained_schedule_bounds_mutants():

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from conftest import given, mixed_chain, settings, st
 
 from repro import kernels as K
 from repro.configs import get_reduced
@@ -255,6 +255,32 @@ def test_ragged_chained_dead_blocks_execute_zero_steps():
         assert executed == expected, (vi, executed, expected)
         assert total - executed == total * (1 - vi / b), \
             "skip ratio != 1 - n/bucket"
+
+
+@pytest.mark.parametrize("valid_images", [1, 2])
+def test_ragged_chained_mixed_sources_skip_dead_steps_and_windows(
+        valid_images):
+    """A launch over x, panel and ring sources (3x3 and 5x5 over two ring
+    columns, two n-blocks; 196-row images in 128-row blocks): the live
+    rows bit-match the dense launch on just the request's images, the
+    live tail block stores zeros past m_valid, and the kernel runs only
+    the live blocks' steps and builds only their ring windows."""
+    phases, x, panel, m = mixed_chain()
+    mv = valid_images * 14 * 14
+    live = -(-mv // 128)
+    got, cnt = gmm.grouped_matmul_chained(
+        phases(x), m=m, h=14, w=14, panels=[panel], m_valid=mv,
+        debug_steps=True, interpret=True)
+    oracle = gmm.grouped_matmul_chained(
+        phases(x[:mv]), m=mv, h=14, w=14, panels=[panel[:mv]],
+        interpret=True)
+    _assert_chained_ragged(got, oracle, mv)
+    spec = gmm._chain_static(phases(x), 128, 128, 14)
+    tab = np.asarray(gmm._plan_tiles_chained(-(-m // 128), spec))
+    from repro.analysis import tables
+    cnt = np.asarray(cnt)
+    assert int(cnt[0, 0]) == int((tab[tables.CH_I] < live).sum())
+    assert int(cnt[0, 1]) == live * 2
 
 
 # ---------------------------------------------------------------------------

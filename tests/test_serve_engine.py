@@ -100,3 +100,27 @@ def test_engine_holds_the_plan_caches_entries(engine):
         assert plan_cache.cached_cnn_plan(TINY, b, chain_modules=True) \
             is entry
         assert entry.executable is not None
+
+
+def test_setup_counts_the_chained_launches_steps():
+    """``setup`` counts per bucket what the chained launches do, read off
+    their offset tables as the warm dispatch traces the step; an engine
+    whose executables were traced before it reads the same counts from an
+    abstract trace.  The window builds sit far below the ring steps: one
+    per (phase, block, ring column), not one per tap."""
+    from repro.configs import get_reduced
+    cfg = get_reduced("googlenet")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    first = serve.CNNServer(cfg, params, max_images=2)
+    first.warm()
+    again = serve.CNNServer(cfg, params, max_images=2)
+    again.warm()
+    for eng in (first, again):
+        assert set(eng.setup["chained_steps"]) == set(eng.ladder)
+    s = first.setup
+    assert again.setup["chained_steps"] == s["chained_steps"]
+    assert again.setup["ring_window_builds"] == s["ring_window_builds"]
+    for b in first.ladder:
+        steps, builds = s["chained_steps"][b], s["ring_window_builds"][b]
+        assert steps["x"] > 0 and steps["ring"] > 0
+        assert 0 < builds * 9 <= steps["ring"]
