@@ -34,7 +34,7 @@ class Serving:
     def __init__(self, cell, seed: int, *, wrap=None, interpret=None):
         self.cell = cell
         tr = cell.traffic
-        self.cfg = program.cnn_config(cell.sizes)
+        self.cfg = cell.ref.program_config(cell.sizes)
         self.server = program.Server(
             self.cfg, cell.ref.make_params(cell.sizes, seed),
             tr["max_images"], chain_modules=tr["chain_modules"], wrap=wrap,
@@ -229,9 +229,10 @@ def run(cell, seed: int, seconds: float, tracer, counter, t_start: float,
     imgs = np.concatenate([w["images"][r] for r in rids]) if rids \
         else np.zeros((0,) + tuple(serving.cfg.img), np.float32)
     ideal = sum(work.ideal_s(work.plan_ops(
-        work.forward_ops(cell.sizes, int(n))), peaks) for n in w["valid"])
+        cell.ref.forward_ops(cell.sizes, int(n))), peaks)
+        for n in w["valid"])
     walls, valid = list(w["walls"]), list(w["valid"])
-    fwd_flops = work.forward_flops(cell.sizes, 1)
+    fwd_flops = work.forward_flops(cell.ref.forward_ops(cell.sizes, 1))
     failed = s["requests"] - s["answered"]
     misses = w["plan_cache_misses"]
     del w, serving

@@ -1,5 +1,6 @@
-"""The harness's one door into the program under test: its entry points,
-called as its own launch scripts call them.
+"""The harness's door into the program under test: its serving entry
+points, called as its own launch scripts call them.  The program's config
+comes from the configuration's module (``program_config``).
 
 Only ``dispatch`` repeats program logic: the per-dispatch packing of
 ``launch/serve.py:serve_cnn_metrics``, which has no public entry that takes
@@ -12,17 +13,6 @@ import importlib
 import time
 
 import numpy as np
-
-
-def cnn_config(sizes: dict):
-    """The program's ``CNNConfig`` for an inception configuration file."""
-    from repro.models.cnn import CNNConfig, InceptionSpec
-    return CNNConfig(
-        name=sizes["name"], img=tuple(sizes["img"]),
-        stem=tuple(tuple(s) for s in sizes["stem"]),
-        modules=tuple(InceptionSpec(*m) for m in sizes["modules"]),
-        pool_between=tuple(sizes["pool_between"]),
-        num_classes=sizes["num_classes"])
 
 
 def serve_ladder(max_images: int, rows_per_image: int) -> list[int]:

@@ -1,4 +1,23 @@
-"""``BENCHMARK.json`` and the files it names, resolved for one cell."""
+"""``BENCHMARK.json`` and the files it names, resolved for one cell.
+
+A configuration's module, ``configs/<config>.py`` beside its sizes, is the
+one place that knows its network.  It provides:
+
+  make_params(sizes, seed)    the weights, made on the device from the seed
+  logits(params, sizes, images, precision)
+                              the plain reference's logits
+  program_config(sizes)       the program's config object, built through
+                              the program's public API; it has ``img``,
+                              and ``plan_cnn`` and ``make_cnn_serve_step``
+                              take it
+  forward_ops(sizes, batch)   the forward's work op by op, a list of
+                              ``work.OpWork`` written with ``work.conv``
+                              and ``work.pool``; ``kernel`` marks the ops
+                              the plan's kernels run
+
+``load_cell`` refuses a module that lacks one of them, before any device
+work.
+"""
 from __future__ import annotations
 
 import dataclasses
@@ -7,6 +26,9 @@ import json
 import re
 import pathlib
 import sys
+
+CONFIG_FUNCTIONS = ("make_params", "logits", "program_config",
+                    "forward_ops")
 
 
 @dataclasses.dataclass
@@ -18,7 +40,7 @@ class Cell:
     traffic: dict               # traffic/<traffic>.json
     end_to_end: list            # metric entries this cell reports
     per_layer: list
-    ref: object = None          # configs/<config>.py, the plain reference
+    ref: object = None          # configs/<config>.py, its network
 
     @property
     def name(self) -> str:
@@ -71,8 +93,12 @@ def load_cell(root, workload: str) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if m["moves"] in reported and _reported_in(m, workload)]
     cell = Cell(root, w, cfg, sizes, traffic, e2e, per_layer)
-    cell.ref = load_module((root / cfg["file"]).with_suffix(".py"),
-                           f"chipbench_config_{cfg['name']}")
+    path = (root / cfg["file"]).with_suffix(".py")
+    cell.ref = load_module(path, f"chipbench_config_{cfg['name']}")
+    for fn in CONFIG_FUNCTIONS:
+        if not callable(getattr(cell.ref, fn, None)):
+            raise AttributeError(f"{path} has no {fn}(): a configuration's "
+                                 f"module provides {', '.join(CONFIG_FUNCTIONS)}")
     return cell
 
 

@@ -5,9 +5,13 @@ float32 with every contraction at the precision the caller names:
 passes (what ``Precision.HIGH`` is on a TPU), spelled out here so that it
 computes alike on any backend.
 
-It imports nothing of the program under test.  The weights it makes are
-the benchmark's, laid out as the program takes them, and the harness hands
-the same function's output to the program.
+The reference (``make_params``, ``logits``) imports nothing of the program
+under test.  The weights it makes are the benchmark's, laid out as the
+program takes them, and the harness hands the same function's output to
+the program.  Beside it stand the network's two other faces that the
+harness needs (``chipbench/spec.py``): ``program_config``, the program's
+config built through its public API, and ``forward_ops``, the work of the
+forward op by op.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import pathlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from chipbench import work
 
 SIZES = json.loads(pathlib.Path(__file__).with_suffix(".json").read_text())
 
@@ -151,3 +156,50 @@ def logits(params, sizes: dict, images: np.ndarray, precision: str,
                                             x.dtype)])
         out.append(np.asarray(fn(params, jnp.asarray(x)))[:n])
     return np.concatenate(out)
+
+
+def program_config(sizes: dict):
+    """The program's ``CNNConfig`` for an inception configuration file."""
+    from repro.models.cnn import CNNConfig, InceptionSpec
+    return CNNConfig(
+        name=sizes["name"], img=tuple(sizes["img"]),
+        stem=tuple(tuple(s) for s in sizes["stem"]),
+        modules=tuple(InceptionSpec(*m) for m in sizes["modules"]),
+        pool_between=tuple(sizes["pool_between"]),
+        num_classes=sizes["num_classes"])
+
+
+def forward_ops(sizes: dict, batch: int) -> list[work.OpWork]:
+    """Every op of ``forward`` at ``batch`` images, in execution order.
+    The plan's kernels run the stem's and the modules' convolutions."""
+    dtype = sizes["dtype"]
+    ops: list[work.OpWork] = []
+
+    def conv(name, x, cout, k, s=1):
+        op, y = work.conv(name, x, cout, k, k, s, dtype=dtype, kernel=True)
+        ops.append(op)
+        return y
+
+    def maxpool(name, x, s):
+        op, y = work.pool(name, x, 3, s, "SAME", "max", dtype=dtype)
+        ops.append(op)
+        return y
+
+    x = (batch, *sizes["img"])
+    for i, (k, cout, s) in enumerate(sizes["stem"]):
+        x = conv(f"stem{i}", x, cout, k, s)
+    for i, (n1, r3, n3, r5, n5, pp) in enumerate(sizes["modules"]):
+        nm = f"inc{i}"
+        if i in sizes["pool_between"]:
+            x = maxpool(f"{nm}/pool", x, 2)
+        conv(f"{nm}/1x1", x, n1, 1)
+        conv(f"{nm}/3x3", conv(f"{nm}/r3", x, r3, 1), n3, 3)
+        conv(f"{nm}/5x5", conv(f"{nm}/r5", x, r5, 1), n5, 5)
+        conv(f"{nm}/pp", maxpool(f"{nm}/pppool", x, 1), pp, 1)
+        x = x[:3] + (n1 + n3 + n5 + pp,)
+    op, x = work.pool("gap", x, x[1], 1, "VALID", "avg", dtype=dtype)
+    ops.append(op)
+    # the classifier: a 1x1 convolution over the pooled 1x1 image
+    ops.append(work.conv("head", x, sizes["num_classes"], 1, 1, dtype=dtype,
+                         kernel=False)[0])
+    return ops

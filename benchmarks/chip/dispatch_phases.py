@@ -39,7 +39,6 @@ import statistics
 import sys
 import time
 
-from chipbench import program
 from chipbench import trace as T
 from chipbench.kinds import serve_open_loop as S
 
@@ -100,7 +99,7 @@ class EngineServing(S.Serving):
 
     def __init__(self, cell, seed: int, *, interpret=None):
         self.cell = cell
-        self.cfg = program.cnn_config(cell.sizes)
+        self.cfg = cell.ref.program_config(cell.sizes)
         self.server = EngineServer(
             self.cfg, cell.ref.make_params(cell.sizes, seed),
             cell.traffic["max_images"],

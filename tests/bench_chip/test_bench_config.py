@@ -2,12 +2,12 @@
 flags drift (the benchmark keeps its own copy so that a program change
 cannot move the yardstick), not a gate on the program."""
 import chiptiny
-from chipbench import program
 
 
 def test_pinned_googlenet_matches_the_programs_config():
     from repro.configs.googlenet import CONFIG
-    pinned = program.cnn_config(chiptiny.googlenet_sizes())
+    pinned = chiptiny.reference_module().program_config(
+        chiptiny.googlenet_sizes())
     assert pinned.img == CONFIG.img
     assert pinned.stem == CONFIG.stem
     assert pinned.modules == CONFIG.modules
@@ -25,7 +25,7 @@ def test_reference_params_fit_the_program_layout():
     ours = jax.eval_shape(lambda: ref.init_params(g, jnp.zeros(2,
                                                               jnp.uint32)))
     theirs = jax.eval_shape(lambda: cnn.init_params(
-        program.cnn_config(g), jax.random.PRNGKey(0)))
+        ref.program_config(g), jax.random.PRNGKey(0)))
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     assert [x.shape for x in jax.tree.leaves(ours)] == \
         [x.shape for x in jax.tree.leaves(theirs)]

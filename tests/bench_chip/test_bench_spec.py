@@ -82,3 +82,14 @@ def test_every_cell_of_a_kind_finds_its_readers(tmp_path):
     assert cell.per_layer
     for m in cell.per_layer:
         assert callable(spec.metric_reader(root, m["name"]).read)
+
+
+@pytest.mark.parametrize("missing", ["program_config", "forward_ops"])
+def test_config_module_without_a_contract_function_is_refused(tmp_path,
+                                                              missing):
+    root = chiptiny.make_root(tmp_path / "co")
+    mod = root / "benchmarks" / "chip" / "configs" / "tiny.py"
+    mod.write_text(mod.read_text().replace(f"def {missing}(",
+                                           f"def _{missing}("))
+    with pytest.raises(AttributeError, match=f"tiny.py has no {missing}"):
+        spec.load_cell(root, "tiny-serve")
