@@ -17,6 +17,17 @@ one place that knows its network.  It provides:
 
 ``load_cell`` refuses a module that lacks one of them, before any device
 work.
+
+Beside the module, ``configs/<config>.json`` holds the sizes as they are
+run.  It carries ``name``, the configs entry's name, and
+``matmul_precision``, the precision the configuration states, at which the
+harness runs the reference.  The rest of its keys are the module's.
+
+The profiler's trace names each Pallas kernel by the ``plan[mode:op]``
+scope it ran in, and the harness recognises a kernel by that mode, one of
+the program's plan modes, and that op, one that ``forward_ops`` lists and
+marks ``kernel`` (``Cell.kernel_ops``).  So a configuration's op names in
+``forward_ops`` are the names its plan gives the same ops.
 """
 from __future__ import annotations
 
@@ -26,6 +37,8 @@ import json
 import re
 import pathlib
 import sys
+
+from chipbench import work
 
 CONFIG_FUNCTIONS = ("make_params", "logits", "program_config",
                     "forward_ops")
@@ -53,6 +66,13 @@ class Cell:
     @property
     def chips(self) -> int:
         return int(self.workload["chips"])
+
+    @property
+    def kernel_ops(self) -> list[str]:
+        """The names of the ops the plan's kernels run, by ``forward_ops``:
+        the trace reads a kernel's scope by them."""
+        return [op.name for op in
+                work.plan_ops(self.ref.forward_ops(self.sizes, 1))]
 
 
 def bench_dir(root: pathlib.Path) -> pathlib.Path:

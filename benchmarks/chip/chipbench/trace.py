@@ -2,6 +2,12 @@
 metrics read: device busy time, device time inside each ``plan[mode:op]``
 scope, kernel launches, and the idle gaps named by what the host was doing.
 
+A kernel's scope is read from its instruction's name, by the program's plan
+modes (``repro.core.plan.MODES``) and the configuration's kernel ops: the
+names of the ops its ``forward_ops`` marks ``kernel``.  So the reduction
+knows no network, and a kernel named for an op the configuration does not
+list is an error, not a launch outside the roofline.
+
 Reading the file (``load``) is kept apart from the arithmetic, which works
 on plain ``Event`` lists so that a test can hand it a synthesized trace.
 """
@@ -22,14 +28,11 @@ OPS_LINE = "XLA Ops"
 #: host spans the harness writes around each phase of a window
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
-#: a ``plan[mode:op]`` scope as a name stack keeps it, and as XLA's
-#: instruction names keep it ("plan_grouped_chained_inc1.r5_.2"), behind
-#: "jvp_" in a forward that is differentiated and "transpose_jvp_" in its
-#: backward
+#: a ``plan[mode:op]`` scope as a name stack keeps it
 _SCOPE = re.compile(r"plan\[([^\]]+)\]")
-_HLO_SCOPE = re.compile(
-    r"^%?((?:transpose_)?(?:jvp_)?)plan_([a-z_]+?)_((?:stem|inc)\d+"
-    r"(?:\.[0-9a-z]+)?)_")
+#: the start of a scope as XLA's instruction names keep it, behind "jvp_"
+#: in a forward that is differentiated and "transpose_jvp_" in its backward
+_HLO_PREFIX = r"^%?((?:transpose_)?(?:jvp_)?)plan_"
 _HLO_NAME = re.compile(r"^%?([A-Za-z0-9_.\-]+?)(?:\.\d+)? = ")
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
@@ -114,13 +117,31 @@ def gaps(events, lo: float, hi: float) -> list[tuple[float, float]]:
     return out
 
 
-def scope_of(e: Event) -> str | None:
+def _alternatives(names) -> str:
+    """A regex alternation of ``names``, longest first, so that a name that
+    extends another ("inc3.b7x7_1", "inc3.b7x7") is matched as itself."""
+    return "|".join(re.escape(n) for n in sorted(set(names), key=len,
+                                                 reverse=True))
+
+
+def scope_pattern(kernel_ops) -> re.Pattern:
+    """The instruction-name pattern of a plan scope for a configuration
+    whose kernel ops are ``kernel_ops``: "plan_", a mode of the program's
+    plan, "_", then one of those ops as XLA spells it ("/" becomes ".") and
+    "_".  The op's group is None where the mode matches and no op does."""
+    from repro.core.plan import MODES
+    ops = _alternatives(op.replace("/", ".") for op in kernel_ops)
+    return re.compile(
+        f"{_HLO_PREFIX}({_alternatives(MODES)})_(?:({ops})_)?")
+
+
+def scope_of(e: Event, pattern: re.Pattern) -> str | None:
     """The ``plan[mode:op]`` scope an op ran in, as "mode:op" with " bwd"
-    for the backward's ops: from the XLA instruction's name, which a
-    Pallas kernel takes from the scope, or from a name stack in any stat
-    (the innermost where scopes nest)."""
-    m = _HLO_SCOPE.match(e.name)
-    if m:
+    for the backward's ops: from the XLA instruction's name by ``pattern``
+    (``scope_pattern``), which a Pallas kernel takes from the scope, or from
+    a name stack in any stat (the innermost where scopes nest)."""
+    m = pattern.match(e.name)
+    if m and m.group(3):
         bwd = " bwd" if m.group(1).startswith("transpose") else ""
         return f"{m.group(2)}:{m.group(3).replace('.', '/')}{bwd}"
     found = None
@@ -136,14 +157,16 @@ def is_kernel_launch(e: Event) -> bool:
     return KERNEL in e.name or any(KERNEL in v for _k, v in e.stats)
 
 
-def op_key(e: Event) -> str:
+def _instruction(e: Event) -> str:
+    m = _HLO_NAME.match(e.name)
+    return m.group(1) if m else e.name[:40]
+
+
+def op_key(e: Event, pattern: re.Pattern) -> str:
     """How the breakdown names an op: its plan scope where it has one, else
     "xla:" and the instruction's name without its number."""
-    sc = scope_of(e)
-    if sc:
-        return f"plan[{sc}]"
-    m = _HLO_NAME.match(e.name)
-    return "xla:" + (m.group(1) if m else e.name[:40])
+    sc = scope_of(e, pattern)
+    return f"plan[{sc}]" if sc else "xla:" + _instruction(e)
 
 
 #: idle gaps shorter than this lie between two ops of one program
@@ -179,9 +202,13 @@ def window_of(trace: Trace) -> tuple[float, float]:
     return min(s.start_ns for s in wins), max(s.end_ns for s in wins)
 
 
-def reduce(trace: Trace, top: int = 10) -> dict:
+def reduce(trace: Trace, kernel_ops, top: int = 10) -> dict:
     """Everything the per-layer metrics read from a trace, over the
-    ``bench.window`` span, averaged over the chips that ran ops."""
+    ``bench.window`` span, averaged over the chips that ran ops.
+    ``kernel_ops`` are the configuration's kernel ops (``Cell.kernel_ops``);
+    a kernel named for a plan mode and none of them is refused, since its
+    time would fall out of the plan's kernels."""
+    pattern = scope_pattern(kernel_ops)
     lo, hi = window_of(trace)
     planes = list(trace.device_ops.values())
     if not planes:
@@ -198,8 +225,14 @@ def reduce(trace: Trace, top: int = 10) -> dict:
             dur = min(e.end_ns, hi) - max(e.start_ns, lo)
             if e.name not in seen:
                 launch = is_kernel_launch(e)
-                seen[e.name] = (op_key(e), launch,
-                                launch and scope_of(e) is not None)
+                m = pattern.match(e.name)
+                if launch and m and not m.group(3):
+                    raise RuntimeError(
+                        f"the kernel {_instruction(e)} is named for a plan "
+                        f"scope of none of the configuration's kernel ops "
+                        f"(the ops its forward_ops marks kernel)")
+                seen[e.name] = (op_key(e, pattern), launch,
+                                launch and scope_of(e, pattern) is not None)
             key, launch, planned = seen[e.name]
             by_key[key] = by_key.get(key, 0.0) + dur / n
             launches += launch
@@ -267,8 +300,8 @@ class Tracer:
             self.wall_s = time.perf_counter() - t0
             jax.profiler.stop_trace()
 
-    def reduce(self) -> dict:
+    def reduce(self, kernel_ops) -> dict:
         try:
-            return reduce(load(self.dir))
+            return reduce(load(self.dir), kernel_ops)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)

@@ -393,7 +393,7 @@ def main(argv=None) -> int:
     tr, other = load(tracer.dir)
     bench_only = T.Trace(tr.device_ops, [s for s in tr.host_spans
                                          if s.name.startswith(T.SPAN_PREFIX)])
-    red = T.reduce(bench_only)
+    red = T.reduce(bench_only, cell.kernel_ops)
     res = {"device": info, "seed": args.seed, "seconds": args.seconds,
            "setup_s": run["setup_s"], "setup": run["setup"],
            "counters": run["counters"], "summaries": run["summaries"],

@@ -1,8 +1,8 @@
 """Share of the roofline of the planned ops in serving: the least time the
-chip could take for the stem's and the modules' convolutions on the valid
-images of the window's dispatches (``work.py``), over the device time of
-every kernel (Pallas custom call) the trace names by its
-``plan[mode:op]`` scope."""
+chip could take for the ops the configuration's ``forward_ops`` marks
+``kernel``, on the valid images of the window's dispatches (``work.py``),
+over the device time of every kernel (Pallas custom call) the trace names
+by its ``plan[mode:op]`` scope."""
 
 
 def read(ctx):

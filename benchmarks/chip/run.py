@@ -108,7 +108,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace_on: bool,
     red = None
     if trace_on:
         t0 = time.perf_counter()
-        red = tracer.reduce()
+        red = tracer.reduce(cell.kernel_ops)
         log(f"trace reduced in {time.perf_counter() - t0:.3f} s: window "
             f"{red['window_s']:.3f} s, busy {red['busy_s']:.3f} s, "
             f"launches {red['launches']}, harness spans {red['spans']}, "

@@ -67,7 +67,7 @@ def test_shares_and_outside_add_up_to_the_idle_share():
     t = _trace()
     t.device_ops["/device:TPU:1"] = [op(15, 30), op(65, 22)]
     r = P.dispatch_phases(t)
-    busy = T.reduce(t)["busy_s"]
+    busy = T.reduce(t, ())["busy_s"]
     parts = (r["idle_lead_share"] + r["idle_inner_share"]
              + r["idle_tail_share"] + r["idle_outside_share"])
     assert parts == pytest.approx(100.0 * (1 - busy / 0.1))
