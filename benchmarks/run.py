@@ -96,10 +96,10 @@ def _dead_block_skip():
           "src": ("ring", 3, 3, (0,)), "ring_write": None}],
     ]
     _, steps = gmm.grouped_matmul_chained(
-        phases, m=m, h=h, w=w, m_valid=h * w, debug_steps=True,
+        phases, m=m, h=h, w=w, bm=128, m_valid=h * w, debug_steps=True,
         interpret=True)
     tab = np.asarray(gmm._plan_tiles_chained(
-        m // 128, gmm._chain_static(phases, 128, 128, w)))
+        m // 128, gmm._chain_static(phases, 128, w)))
     total = tab.shape[1]
     executed = int(jnp.asarray(steps)[0, 0])
     return {

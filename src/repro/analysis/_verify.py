@@ -104,12 +104,12 @@ def _checked_bwd(mb, kbs, nbs):
 
 
 @functools.lru_cache(maxsize=1024)
-def _checked_chained(mb, spec, h, w, nring):
+def _checked_chained(mb, spec, h, w, nring, bm):
     gm = _gm()
     tab = gm._plan_tiles_chained(mb, spec)
     raw = list(tables.check_chained(tab, mb, spec))
     raw += hazards.check_chained_schedule(tab, mb, len(spec), h=h, w=w,
-                                          bm=BLK, nring=nring)
+                                          bm=bm, nring=nring)
     raw += hazards.check_chained_masked(tab, mb, len(spec), h=h, w=w)
     return tuple(raw)
 
@@ -223,14 +223,14 @@ def _verify_chained(graph, g, where, direction):
                 f"{where}/phase{p}", "bwd")
             out += sub
         return out
-    mb, spec, oh, ow, nring, raw = _budgets.chained_spec(graph, g.chain,
-                                                         g.pools)
+    bm, mb, spec, oh, ow, nring, raw = _budgets.chained_spec(
+        graph, g.chain, g.pools)
     out = _findings(raw, "chained", where)
     if spec is None:
         return out
     if g.chunk_rows:
-        mb = _ceil(g.chunk_rows, BLK)     # each chunk's own launch table
-    return out + _findings(_checked_chained(mb, spec, oh, ow, nring),
+        mb = _ceil(g.chunk_rows, bm)      # each chunk's own launch table
+    return out + _findings(_checked_chained(mb, spec, oh, ow, nring, bm),
                            "chained", where)
 
 
@@ -289,7 +289,11 @@ def _verify_budget(graph, g, where, direction, budgets):
         opset = {n for ph in chain for n in ph}
         ring = frozenset(n for ph in chain for n in ph
                          if graph.pred[n] & opset)
-        fp = _budgets.chained_footprint(graph, chain, ring, block=BLK)
+        try:
+            fp = _budgets.chained_footprint(graph, chain, ring,
+                                            pools=g.pools)
+        except ValueError:
+            return out      # malformed: ``_verify_chained`` reports it
     else:
         names = tuple(_strip(n) for n in g.ops)
         algs = {_strip(k): v for k, v in g.algorithms.items()}

@@ -27,10 +27,12 @@ The accounting, in one place:
                    ``cost_model.backward_profiles``), never added to
                    the forward's.
   chained          ``cost_model.chained_profiles`` workspace (ring
-                   consumers drop their patch buffer) plus the launch's
-                   ring scratch: 3 wave slots and a (3*bm, blk) shift
-                   window per ring column, the rows' (h, w) and the f32
-                   accumulator.
+                   consumers drop their patch buffer) plus what the
+                   launch holds in VMEM at its M-block
+                   (``kernels.grouped_matmul.chained_vmem_bytes`` at
+                   ``chained_block_m``): its tiles, the f32
+                   accumulator and the ring scratch, 3 wave slots and a
+                   (3*bm, blk) shift window per ring column.
   SMEM             every grouped-family launch prefetches its offset
                    table (one column per grid step, linear in M) into
                    the chip's SMEM (``cost_model.SMEM_PREFETCH_BYTES``).
@@ -112,28 +114,23 @@ def group_footprint(graph, names, algorithms, *, pools=(),
     return Footprint(ws + extra_ws, vmem + extra_vmem)
 
 
-def chained_footprint(graph, phases, ring, *, block: int = 128) -> Footprint:
+def chained_footprint(graph, phases, ring, *, pools=()) -> Footprint:
     """The static footprint of one chained launch: chained-priced GEMM
-    workspace (ring consumers' lhs never exists outside VMEM) plus the
-    VMEM ring scratch — per ring column over every consumed producer's K
-    blocks 3 wave slots and a (3*bm, blk) shift window; with any ring, the
-    int32 (h, w) of a block's rows (2 blocks) and the coordinate operand
-    (w + bm rows, at most 2 blocks: a tap's halo is at most bm) — and the
-    f32 accumulator."""
+    workspace (ring consumers' lhs never exists outside VMEM) plus what
+    the launch holds in VMEM at the M-block it runs at — its tiles, the
+    f32 accumulator and, per ring column, 3 wave slots and a (3*bm, blk)
+    shift window (``chained_vmem_bytes``).  Raises ValueError on a chain
+    ``chained_spec`` finds malformed."""
     ops = [graph.ops[n] for ph in phases for n in ph]
     profs = cm.chained_profiles(ops, ring)
-    allnames = {m for ph in phases for m in ph}
-    consumed: set[str] = set()
-    for ph in phases:
-        for n in ph:
-            if n in ring:
-                consumed |= graph.pred[n] & allnames
-    nring = sum(-(-graph.ops[n].p["k"] // block) for n in consumed)
-    eb = max(op.dtype_bytes for op in ops)
-    ring_vmem = (6 * nring * eb + (1 + (4 if nring else 0)) * 4) \
-        * block * block
+    bm, _mb, spec, _oh, ow, _nring, problems = chained_spec(graph, phases,
+                                                            pools)
+    if spec is None:
+        raise ValueError("; ".join(msg for _, msg in problems))
+    vmem = _gm().chained_vmem_bytes(spec, bm, ow,
+                                    max(op.dtype_bytes for op in ops))
     return Footprint(sum(p.workspace_bytes for p in profs),
-                     sum(p.vmem_bytes for p in profs) + ring_vmem)
+                     sum(p.vmem_bytes for p in profs) + vmem)
 
 
 # ---------------------------------------------------------------------------
@@ -158,9 +155,11 @@ def _strip(n: str) -> str:
 def chained_spec(graph, chain, pools=()):
     """Rebuild the hashable chained-launch spec ``_chain_static`` would
     produce, from the graph alone.  ``chain`` is the group's phases of op
-    names, ``pools`` its (branch, pool) riders.  Returns (mb, spec, oh,
-    ow, nring, problems) — spec None when the chain is malformed, and
-    ``problems`` a list of (checker, message) pairs.
+    names, ``pools`` its (branch, pool) riders.  Returns (bm, mb, spec,
+    oh, ow, nring, problems) — the launch's M-block
+    (``chained_block_m``) and M-block count, spec None (and bm, mb
+    None) when the chain is malformed, and ``problems`` a list of
+    (checker, message) pairs.
 
     A branch whose lhs comes from OUTSIDE the launch is specced as a
     packed-x source: the panel-descriptor block numbering needs the
@@ -218,8 +217,8 @@ def chained_spec(graph, chain, pools=()):
         out.append(("schema", f"chained phases disagree on shared M: "
                     f"{sorted(ms)} — the wave schedule advances all phases "
                     "over one row space"))
-        return None, None, oh, ow, nring, out
-    mb = _ceil(ms.pop(), BLK)
+        return None, None, None, oh, ow, nring, out
+    m = ms.pop()
 
     spec = []
     for ph in chain:
@@ -235,17 +234,17 @@ def chained_spec(graph, chain, pools=()):
                     out.append(("schema", f"ring consumer {n} has stride "
                                 f"{op.p['stride']} — the shifted-window "
                                 "ring only streams stride-1 taps"))
-                    return None, None, oh, ow, nring, out
+                    return None, None, None, oh, ow, nring, out
                 taps = []
                 for dh in range(kh):
                     for dw in range(kw):
                         delta = (dh - kh // 2) * ow + (dw - kw // 2)
                         if abs(delta) > BLK:
                             out.append(("bounds", f"ring consumer {n} halo "
-                                        f"{delta} exceeds bm={BLK} (W={ow}, "
+                                        f"{delta} exceeds {BLK} rows (W={ow}, "
                                         f"k={kh}x{kw}) — chain-ineligible "
                                         "geometry"))
-                            return None, None, oh, ow, nring, out
+                            return None, None, None, oh, ow, nring, out
                         taps.append((delta, dh - kh // 2, dw - kw // 2))
                 src = ("ring", (tuple(taps), ring_cols[d]))
             elif n in pools:
@@ -255,7 +254,10 @@ def chained_spec(graph, chain, pools=()):
             pspec.append((src[0], src[1], nbb,
                           tuple(ring_cols.get(n, ()))))
         spec.append(tuple(pspec))
-    return mb, tuple(spec), oh, ow, nring, out
+    spec = tuple(spec)
+    bm = _gm().chained_block_m(spec, m, ow, max(
+        graph.ops[n].dtype_bytes for ph in chain for n in ph))
+    return bm, _ceil(m, bm), spec, oh, ow, nring, out
 
 
 def group_launches(graph, g, directions=("fwd",)):
@@ -270,13 +272,13 @@ def group_launches(graph, g, directions=("fwd",)):
     gm = _gm()
     launches = []
     if g.mode == "grouped_chained":
-        mb, spec, _oh, _ow, _nring, problems = chained_spec(
+        bm, _mb, spec, _oh, _ow, _nring, problems = chained_spec(
             graph, g.chain, g.pools)
         if spec is None:
             raise ValueError("; ".join(msg for _, msg in problems))
         m = cm.gemm_shape(graph.ops[_strip(g.chain[0][0])])[0]
         if "fwd" in directions:
-            launches.append((m, BLK, gm._plan_tiles_chained(1, spec).shape,
+            launches.append((m, bm, gm._plan_tiles_chained(1, spec).shape,
                              len(spec), ((2,),)))
         if "bwd" in directions:
             # the VJP's ONE combined dx + dW/db launch per phase, each

@@ -406,28 +406,25 @@ def _chain_feasible(graph: OpGraph, phase0: list[str], branches: list[str],
 
 def _chain_budgets_ok(graph: OpGraph, phases: list[list[str]], ring, *,
                       hbm_budget: float, vmem_budget: float,
-                      block: int = 128, pools=(),
-                      train: bool = False) -> bool:
+                      pools=(), train: bool = False) -> bool:
     """C2 re-check on the chained launch: the HBM workspace of its
     chained-priced GEMM lowering (ring consumers drop their patch buffer —
-    their lhs never exists outside VMEM) plus the launch's ring scratch
-    against the VMEM budget: 3 wave slots and a (3*bm, blk) shift window
-    per ring column, and the f32 accumulator.  The footprint itself comes
-    from ``analysis.budgets.chained_footprint``.  SMEM: a one-image chunk
-    of the launch (and, ``train``, of its backward) must fit — chunking
+    their lhs never exists outside VMEM) plus what the launch holds in
+    VMEM at its M-block, against the VMEM budget: its tiles, the f32
+    accumulator and, per ring column, 3 wave slots and a (3*bm, blk)
+    shift window.  The footprint itself comes from
+    ``analysis.budgets.chained_footprint``.  SMEM: a one-image chunk of
+    the launch (and, ``train``, of its backward) must fit — chunking
     splits anything larger (``analysis.budgets.chunk_rows``)."""
-    if not _budgets.chained_footprint(graph, phases, ring,
-                                      block=block).fits(hbm_budget,
-                                                        vmem_budget):
-        return False
     probe = ExecGroup("grouped_chained", tuple(n for ph in phases
                                                 for n in ph), {}, 0.0,
                       pools=tuple(pools), chain=tuple(map(tuple, phases)))
     try:
+        fp = _budgets.chained_footprint(graph, phases, ring, pools=pools)
         _budgets.chunk_rows(graph, probe, _directions(train))
     except ValueError:
         return False
-    return True
+    return fp.fits(hbm_budget, vmem_budget)
 
 
 def _directions(train: bool) -> tuple[str, ...]:
@@ -527,7 +524,7 @@ def _chain_modules(graph: OpGraph, groups: list[ExecGroup], *,
         ring = frozenset(branches)
         if not _chain_budgets_ok(graph, phases, ring,
                                  hbm_budget=hbm_budget,
-                                 vmem_budget=vmem_budget, block=block,
+                                 vmem_budget=vmem_budget,
                                  pools=q.pools + pg.pools, train=train):
             continue
         phase_ops = [[graph.ops[n] for n in ph] for ph in phases]
@@ -585,8 +582,7 @@ def _chain_modules(graph: OpGraph, groups: list[ExecGroup], *,
         ring = frozenset(run[1:])
         if not _chain_budgets_ok(graph, phases, ring,
                                  hbm_budget=hbm_budget,
-                                 vmem_budget=vmem_budget, block=block,
-                                 train=train):
+                                 vmem_budget=vmem_budget, train=train):
             continue
         phase_ops = [[graph.ops[n]] for n in run]
         t = cm.chained_time(phase_ops, ring)

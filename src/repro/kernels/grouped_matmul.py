@@ -132,13 +132,15 @@ def reset_launch_counts() -> None:
 # What the chained launches traced inside ``chained_steps_recording()`` do,
 # summed: the launches, their grid steps by lhs source and their
 # ring-window builds, read off each launch's offset table
-# (``tables.chained_step_counts``).
+# (``tables.chained_step_counts``); and, per launch in trace order, its
+# (M-block, M-block count) under ``blocks``.
 _CHAIN_RECORDERS: list[dict] = []
 
 
 @contextlib.contextmanager
 def chained_steps_recording():
-    rec = {"launches": 0, "x": 0, "ring": 0, "panel": 0, "window_builds": 0}
+    rec = {"launches": 0, "x": 0, "ring": 0, "panel": 0, "window_builds": 0,
+           "blocks": []}
     _CHAIN_RECORDERS.append(rec)
     try:
         yield rec
@@ -2015,7 +2017,7 @@ def chained_layout(phases, blk: int = 128):
     return out
 
 
-def _chain_static(phases, blk, bm, wimg):
+def _chain_static(phases, blk, wimg):
     """Hashable planner spec + validation for one chained launch."""
     spec = []
     for phase in phases:
@@ -2034,9 +2036,6 @@ def _chain_static(phases, blk, bm, wimg):
                 for dh in range(kh):
                     for dw in range(kw):
                         d = (dh - kh // 2) * wimg + (dw - kw // 2)
-                        assert abs(d) <= bm, (
-                            f"halo {d} exceeds bm={bm} (W={wimg}, "
-                            f"k={kh}x{kw}) — chain ineligible")
                         taps.append((d, dh - kh // 2, dw - kw // 2))
                 src = (tuple(taps), tuple(rcs))
             rwcs = tuple(br.get("ring_write") or ())
@@ -2050,9 +2049,81 @@ def _chain_static(phases, blk, bm, wimg):
     return tuple(spec)
 
 
+def _ring_columns(spec) -> int:
+    """Ring columns of a chained launch: none unless a branch reads the
+    ring, else every column read or written."""
+    flat = [bs for pspec in spec for bs in pspec]
+    reads = [c for bs in flat if bs[0] == "ring" for c in bs[1][1]]
+    writes = [c for bs in flat for c in bs[3]]
+    assert reads or not writes, "a ring write with no ring read"
+    return 1 + max(reads + writes) if reads else 0
+
+
+# ---------------------------------------------------------------------------
+# the chained launch's M-block
+# ---------------------------------------------------------------------------
+#
+# A grid step contracts one (bm, blk) lhs tile with one (blk, blk) weight
+# tile.  The step's fixed cost and its weight tile's fetch do not grow with
+# bm; its MXU passes, lhs and border mask do.  So the launch takes the
+# largest M-block its shape and VMEM allow, and the lane and k blocks stay
+# ``blk``: each output row sums the same products in the same k order at
+# every bm.  The kernel wrapper, the planner's SMEM chunks
+# (``analysis.budgets``) and the hazard replay all read ``chained_block_m``.
+
+#: the M-blocks a chained launch may take, largest first
+CHAINED_BLOCK_MS = (1024, 512, 256, 128)
+#: the VMEM a chained launch's tiles and scratch may hold
+CHAINED_VMEM_BUDGET = 32 * 1024 * 1024
+
+
+def chained_vmem_bytes(spec, bm: int, w: int, itemsize: int,
+                       blk: int = 128) -> int:
+    """VMEM one chained launch holds at M-block ``bm``: double-buffered
+    x, weight, output and (at most two) panel tiles, the bias stack and
+    the f32 accumulator; with a ring, per ring column 3 wave slots and a
+    (3*bm, blk) window, the int32 (h, w) of the block's rows and the
+    (w + bm, blk) coordinate operand.  Panels are counted at the most the
+    kernel addresses, so the planner, which specs a panel lhs as x,
+    prices the launch that runs."""
+    nph = len(spec)
+    nbias = sum(bs[2] for pspec in spec for bs in pspec)
+    nring = _ring_columns(spec)
+    tile = bm * blk
+    held = (2 * (3 + nph) * tile + 2 * blk * blk + nbias * blk) * itemsize \
+        + 4 * tile
+    if nring:
+        held += 6 * nring * tile * itemsize + 4 * (2 * tile + (w + bm) * blk)
+    return held
+
+
+def chained_vmem_limit(vmem: int) -> int:
+    """The chained launch's ``vmem_limit_bytes`` for ``vmem`` bytes of
+    tiles and scratch: twice them and 8 MiB for the compiler's own, at
+    most 96 of the v5e's 128 MiB."""
+    return min(2 * vmem + (8 << 20), 96 << 20)
+
+
+def chained_block_m(spec, m: int, w: int, itemsize: int,
+                    blk: int = 128) -> int:
+    """The M-block of the chained launch ``spec`` (``_chain_static``'s)
+    over ``m`` rows of ``w``-wide images in ``itemsize``-byte elements:
+    the largest of ``CHAINED_BLOCK_MS`` that spans no more ``blk``-row
+    blocks than the launch has and whose ``chained_vmem_bytes`` fit
+    ``CHAINED_VMEM_BUDGET``; else the smallest.  Under the budget it is
+    a function of ``m`` alone, so launches over the same rows (a panel
+    consumer and its producer) take the same M-block and pad their
+    panels alike."""
+    for bm in CHAINED_BLOCK_MS:
+        if bm <= _round_up(m, blk) and chained_vmem_bytes(
+                spec, bm, w, itemsize, blk) <= CHAINED_VMEM_BUDGET:
+            return bm
+    return CHAINED_BLOCK_MS[-1]
+
+
 def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
-                           block: int = 128, m_valid=None,
-                           debug_steps: bool = False,
+                           block: int = 128, bm: int | None = None,
+                           m_valid=None, debug_steps: bool = False,
                            interpret: bool = False,
                            chunk_rows: int | None = None):
     """Execute a chain of grouped branch phases as ONE kernel.
@@ -2096,14 +2167,15 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
     and ``[0, 1]`` the ring windows built (``tables.chained_step_counts``
     gives both for the dense launch).
 
+    ``bm`` is the launch's M-block, a multiple of ``block`` (None:
+    ``chained_block_m``'s choice from the launch's shape); the lane and
+    k blocks stay ``block``.  Returned panels have ``ceil(m/bm)*bm`` rows.
+
     ``chunk_rows`` caps the rows per launch (SMEM chunking, a multiple of
     the h*w image; None sizes it from the table): the chain then runs as
     one launch per image-aligned chunk, outputs stacked along M.
     """
     blk = block
-    bm = blk
-    mb = -(-m // bm)
-    mp = mb * bm
     # dtype: follow the lhs operands
     dtype = None
     for phase in phases:
@@ -2112,7 +2184,16 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
                 dtype = br["src"][1][0].dtype
     if dtype is None:
         dtype = panels[0].dtype if panels else phases[0][0]["w"].dtype
-    spec = _chain_static(phases, blk, bm, w)
+    spec = _chain_static(phases, blk, w)
+    itemsize = jnp.dtype(dtype).itemsize
+    if bm is None:
+        bm = chained_block_m(spec, m, w, itemsize, blk)
+    assert bm % blk == 0, (bm, blk)
+    halo = max((abs(t[0]) for pspec in spec for tag, src, _n, _rw in pspec
+                if tag == "ring" for t in src[0]), default=0)
+    assert halo <= bm, f"halo {halo} exceeds bm={bm} (W={w}): chain ineligible"
+    mb = -(-m // bm)
+    mp = mb * bm
     nph = len(phases)
     hw = h * w
     rows = _launch_rows(m, bm, _plan_tiles_chained(1, spec).shape,
@@ -2130,7 +2211,7 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
                    for phase in phases]
             parts.append(grouped_matmul_chained(
                 sub, m=r, h=h, w=w, panels=[pa[s:s + r] for pa in panels],
-                block=block, m_valid=_chunk_valid(m_valid, s, r),
+                block=block, bm=bm, m_valid=_chunk_valid(m_valid, s, r),
                 debug_steps=debug_steps, interpret=interpret, chunk_rows=r))
         outs = [p[0] if debug_steps else p for p in parts]
         stacked = [_stack_rows([o[p][:r] for o, (_, r)
@@ -2186,19 +2267,15 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
         assert pc % blk == 0, pa.shape
         pads.append(jnp.pad(pa, ((0, mp - pr), (0, 0))) if pr < mp
                     else pa[:mp])
-    # the ring: none unless a branch reads it, else every column read or
-    # written
     has_x = any(bs[0] == "x" for bs in flat_spec)
-    reads = [c for bs in flat_spec if bs[0] == "ring" for c in bs[1][1]]
-    writes = [c for bs in flat_spec for c in bs[3]]
-    assert reads or not writes, "a ring write with no ring read"
-    nring = 1 + max(reads + writes) if reads else 0
+    nring = _ring_columns(spec)
 
     _count_launch("grouped_matmul_chained")
     tab = _device_table(_plan_tiles_chained, mb, spec)
     dims = _device_table(_chain_dims, h, w)
     for rec in _CHAIN_RECORDERS:
         rec["launches"] += 1
+        rec["blocks"].append((bm, mb))
         for k, v in _chained_counts(mb, spec).items():
             rec[k] += v
 
@@ -2275,6 +2352,9 @@ def grouped_matmul_chained(phases, *, m: int, h: int, w: int, panels=(),
                           debug_steps=debug_steps),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=chained_vmem_limit(
+                chained_vmem_bytes(spec, bm, w, itemsize, blk))),
         interpret=interpret,
     )(*scalars, *ins)
     if debug_steps:

@@ -140,8 +140,9 @@ class CNNServer:
     bytes on the host and on the device, and what the bucket's chained
     launches do, read off their offset tables as the step is traced:
     ``chained_steps``, grid steps by lhs source (``x``, ``ring``,
-    ``panel``), and ``ring_window_builds`` (dense counts: a ragged
-    dispatch skips its dead blocks' steps).
+    ``panel``), ``ring_window_builds`` (dense counts: a ragged
+    dispatch skips its dead blocks' steps) and ``chained_bm``, each
+    chained launch's (M-block, M-block count) in trace order.
     """
 
     def __init__(self, cfg, params, max_images: int, *,
@@ -158,7 +159,7 @@ class CNNServer:
         self._plan_cache = plan_cache
         self.setup = {"lower_s": {}, "warm_s": {}, "input_host_bytes": {},
                       "input_device_bytes": {}, "chained_steps": {},
-                      "ring_window_builds": {}}
+                      "ring_window_builds": {}, "chained_bm": {}}
         self.entries, self._steps = {}, {}
         for b in self.ladder:
             t0 = time.perf_counter()
@@ -265,6 +266,7 @@ class CNNServer:
                                                         imgs.dtype),
                                    jax.ShapeDtypeStruct((), jnp.int32))
             self.setup["ring_window_builds"][b] = rec["window_builds"]
+            self.setup["chained_bm"][b] = list(rec["blocks"])
             self.setup["chained_steps"][b] = {
                 k: rec[k] for k in ("x", "ring", "panel")}
 

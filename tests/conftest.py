@@ -103,3 +103,17 @@ def mixed_chain(dtype="float32", b=2, h=14, w=14, key=0):
               "src": ("panel", [(0, 1), (0, 0)]), "ring_write": None}],
         ]
     return phases, x, panel, m
+
+
+def launch_bm(phases, m, h, w):
+    """The M-block ``grouped_matmul_chained`` picks for a launch of
+    ``phases`` over ``m`` rows of (h, w) images: its spec's
+    ``chained_block_m`` in the lhs operands' dtype."""
+    import importlib
+    import jax.numpy as jnp
+    gmm = importlib.import_module("repro.kernels.grouped_matmul")
+    xs = [br["src"][1][0] for ph in phases for br in ph
+          if br["src"][0] == "x"]
+    dtype = xs[0].dtype if xs else phases[0][0]["w"].dtype
+    return gmm.chained_block_m(gmm._chain_static(phases, 128, w), m, w,
+                               jnp.dtype(dtype).itemsize)

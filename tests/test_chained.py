@@ -3,6 +3,7 @@ chain-lowering pass, launch-count pins on googlenet, the partial shared-X
 dedup, and the layout-pass hygiene (zero gather/concat in the counted
 trace) the single-digit-launch claim rests on."""
 import dataclasses
+import functools
 import importlib
 
 import jax
@@ -10,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import mixed_chain, tol_for
+from conftest import launch_bm, mixed_chain, tol_for
 from repro.analysis import tables
 from repro.configs.googlenet import CONFIG as GOOGLENET, reduced
 from repro.core import launch_count as lc
@@ -119,9 +120,9 @@ def test_chained_kernel_gradients_match_reference():
 # kernel-level: one launch over every lhs source, and what each step does
 # ---------------------------------------------------------------------------
 
-def _mixed_outputs(phases, x, panel, m, m_valid=None):
+def _mixed_outputs(phases, x, panel, m, m_valid=None, bm=None):
     return gmm.grouped_matmul_chained(phases(x), m=m, h=14, w=14,
-                                      panels=[panel], m_valid=m_valid,
+                                      panels=[panel], bm=bm, m_valid=m_valid,
                                       debug_steps=True, interpret=True)
 
 
@@ -139,6 +140,7 @@ def test_chained_kernel_mixed_sources_match_reference(dtype, valid_images):
     refs = gmm.grouped_matmul_chained_ref(phases(x), m=m, h=14, w=14,
                                           panels=[panel])
     rows = m if mv is None else mv
+    bm = launch_bm(phases(x), m, 14, 14)
     tol = tol_for(dtype)
     for got, want in zip(outs, refs):
         got = np.asarray(got, np.float32)
@@ -146,25 +148,73 @@ def test_chained_kernel_mixed_sources_match_reference(dtype, valid_images):
                                    np.asarray(want, np.float32)[:rows],
                                    **tol)
         if mv is not None:
-            assert not got[mv:-(-mv // 128) * 128].any()
+            assert not got[mv:-(-mv // bm) * bm].any()
+
+
+@functools.lru_cache(maxsize=None)
+def _mixed_at(images, valid_images, bm):
+    phases, x, panel, m = mixed_chain(b=images)
+    mv = None if valid_images is None else valid_images * 14 * 14
+    outs, cnt = _mixed_outputs(phases, x, panel, m, mv, bm)
+    return [np.asarray(o) for o in outs], np.asarray(cnt)
+
+
+@pytest.mark.parametrize("bm", [128, 256, 512, 1024])
+@pytest.mark.parametrize("images,valid_images", [
+    (2, None), (6, None), (6, 5),
+])
+def test_chained_kernel_at_each_block_m(bm, images, valid_images):
+    """The mixed-source launch at M-block ``bm``: 196-row images, so a
+    512-row block spans 2.6 of them and a 1024-row block 5.2, and a row's
+    h wraps past two image heights; dense, and ragged with the live tail
+    ending inside a block.
+    The live rows bit-match the 128-row launch (each row sums the same
+    products in the same k order) and match the reference; a ragged
+    launch zeroes its live tail block past m_valid; the kernel's own step
+    and window counts are the offset table's at ``bm``."""
+    phases, x, panel, m = mixed_chain(b=images)
+    mv = None if valid_images is None else valid_images * 14 * 14
+    outs, cnt = _mixed_at(images, valid_images, bm)
+    base, _ = _mixed_at(images, valid_images, 128)
+    refs = gmm.grouped_matmul_chained_ref(phases(x), m=m, h=14, w=14,
+                                          panels=[panel])
+    rows = m if mv is None else mv
+    mb = -(-m // bm)
+    for got, b128, want in zip(outs, base, refs):
+        assert got.shape[0] == mb * bm
+        np.testing.assert_array_equal(got[:rows], b128[:rows])
+        np.testing.assert_allclose(got[:rows], np.asarray(want)[:rows],
+                                   **tol_for("float32"))
+        if mv is not None:
+            assert not got[mv:-(-mv // bm) * bm].any()
+    spec = gmm._chain_static(phases(x), 128, 14)
+    tab = np.asarray(gmm._plan_tiles_chained(mb, spec))
+    counts = tables.chained_step_counts(tab, len(spec))
+    live = mb if mv is None else -(-mv // bm)
+    assert int(cnt[0, 0]) == int((tab[tables.CH_I] < live).sum())
+    assert int(cnt[0, 1]) == counts["window_builds"] * live // mb
+    if mv is None:
+        assert int(cnt[0, 0]) == counts["x"] + counts["ring"] \
+            + counts["panel"]
 
 
 @pytest.mark.parametrize("valid_images", [None, 1])
 def test_chained_window_builds_once_per_block_and_ring_column(
         valid_images):
     """The kernel builds a ring column's window once per (phase, block)
-    and every tap and n-block reuses it: 4 blocks x 2 ring columns, all
-    read by phase 1; a ragged launch with one live image (blocks 0 and 1
-    live) builds only the live blocks'.  The table's static count and the
-    kernel's own counter agree."""
+    and every tap and n-block reuses it: the launch's M-blocks x 2 ring
+    columns, all read by phase 1; a ragged launch with one live image
+    builds only the live blocks'.  The table's static count at the
+    launch's M-block and the kernel's own counter agree."""
     phases, x, panel, m = mixed_chain()
-    mb = -(-m // 128)
+    bm = launch_bm(phases(x), m, 14, 14)
+    mb = -(-m // bm)
     mv = None if valid_images is None else valid_images * 14 * 14
     _, cnt = _mixed_outputs(phases, x, panel, m, mv)
-    spec = gmm._chain_static(phases(x), 128, 128, 14)
+    spec = gmm._chain_static(phases(x), 128, 14)
     counts = tables.chained_step_counts(gmm._plan_tiles_chained(mb, spec),
                                         len(spec))
-    live = mb if mv is None else -(-mv // 128)
+    live = mb if mv is None else -(-mv // bm)
     assert counts["window_builds"] == mb * 2
     assert int(np.asarray(cnt)[0, 1]) == live * 2
     per_block = {"x": 2, "panel": 2 + 2, "ring": (9 * 2) * 2 + 25 * 2}
@@ -186,9 +236,10 @@ def test_chained_launch_without_ring_builds_no_window():
                                          panels=[panel])
     np.testing.assert_allclose(np.asarray(outs[0])[:m],
                                np.asarray(ref[0])[:m], **tol_for("float32"))
-    spec = gmm._chain_static([p0], 128, 128, 14)
+    spec = gmm._chain_static([p0], 128, 14)
     counts = tables.chained_step_counts(
-        gmm._plan_tiles_chained(-(-m // 128), spec), 1)
+        gmm._plan_tiles_chained(-(-m // launch_bm([p0], m, 14, 14)), spec),
+        1)
     assert counts["ring"] == counts["window_builds"] == 0
     assert int(np.asarray(cnt)[0, 1]) == 0
     assert int(np.asarray(cnt)[0, 0]) == counts["x"] + counts["panel"]
@@ -308,6 +359,66 @@ def test_googlenet_chained_plan_shape(googlenet_plans):
     assert len(gchains) == 10
     for g in gchains:
         assert all(n.startswith("grad:") for ph in g.chain for n in ph)
+
+
+# ---------------------------------------------------------------------------
+# googlenet serving: planner, hazard replay and kernel agree on the M-block
+# ---------------------------------------------------------------------------
+
+_CHAINED_MODULES = [("grouped_chained", f"inc{i}/r5" if i < 2 else
+                     f"inc{i}/r3") for i in range(9)]
+#: the serving plans' groups, (mode, first op) per bucket
+SERVE_GROUPS = {
+    1: [("serial", "input"), ("grouped_chained", "stem0")]
+    + _CHAINED_MODULES[:7]
+    + [("grouped_pooled", "inc7/r3"), ("grouped_concat", "inc7/3x3"),
+       ("grouped_pooled", "inc8/r3"), ("serial", "inc8/3x3"),
+       ("serial", "inc8/5x5"), ("serial", "inc8/join")],
+}
+for _b in (2, 4, 8):
+    SERVE_GROUPS[_b] = [("serial", "input"), ("grouped_chained", "stem0")] \
+        + _CHAINED_MODULES
+
+
+@pytest.mark.parametrize("bucket", [1, 2, 4, 8])
+def test_serving_planner_replay_and_kernel_agree_on_block_m(bucket):
+    """GoogLeNet at each serving bucket: every chained launch's M-block,
+    M-block count and chunk rows are the same in the planner
+    (``budgets.chained_spec``, ``budgets.chunk_rows``) and in the launches
+    the kernel wrapper makes as the serve step traces; the hazard replay
+    passes at that M-block; the bucket ladder and the plan's groups and
+    their modes are the serving path's."""
+    from repro.analysis import budgets, hazards
+    from repro.core import cost_model as cm
+    from repro.launch.steps import make_cnn_serve_step
+    assert cm.serve_buckets(8, 224 * 224) == [1, 2, 4, 8]
+    plan, _ = CNN.plan_cnn(GOOGLENET, bucket, chain_modules=True)
+    assert [(g.mode, g.ops[0]) for g in plan.groups] == SERVE_GROUPS[bucket]
+    graph = plan.context["graph"]
+    want = []
+    for g in plan.groups:
+        if g.mode != "grouped_chained":
+            continue
+        bm, mb, spec, oh, ow, nring, problems = budgets.chained_spec(
+            graph, g.chain, g.pools)
+        assert problems == []
+        rows, m = budgets.chunk_rows(graph, g)
+        assert rows == (g.chunk_rows or m) and mb == -(-m // bm)
+        for _s, r in gmm._row_spans(m, rows):
+            mbr = -(-r // bm)
+            tab = gmm._plan_tiles_chained(mbr, spec)
+            assert hazards.check_chained_schedule(
+                tab, mbr, len(spec), h=oh, w=ow, bm=bm, nring=nring) == []
+            want.append((bm, mbr))
+    params = jax.eval_shape(
+        lambda: CNN.init_params(GOOGLENET, jax.random.PRNGKey(0)))
+    with gmm.chained_steps_recording() as rec:
+        jax.eval_shape(make_cnn_serve_step(GOOGLENET, plan, interpret=True),
+                       params,
+                       jax.ShapeDtypeStruct((bucket,) + GOOGLENET.img,
+                                            jnp.float32),
+                       jax.ShapeDtypeStruct((), jnp.int32))
+    assert rec["blocks"] == want
 
 
 # ---------------------------------------------------------------------------

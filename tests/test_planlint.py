@@ -281,11 +281,11 @@ def test_budget_fault_injection(fused_plan):
 
 
 def test_smem_budget_fault_injection():
-    # the bucket-8 serving plan runs its stem chain as SMEM chunks: undo
-    # the chunking and planlint must see the 1.5 MiB offset table the
-    # chip's compiler refuses
+    # a 64-image chained plan runs its stem chain as SMEM chunks: undo
+    # the chunking and planlint must see the offset table the chip's
+    # compiler refuses
     import dataclasses
-    plan, _ = cnn.plan_cnn(get_config("googlenet"), 8, chain_modules=True)
+    plan, _ = cnn.plan_cnn(get_config("googlenet"), 64, chain_modules=True)
     assert verify_plan(plan) == []
     i = next(i for i, g in enumerate(plan.groups) if g.ops[0] == "stem0")
     stem = plan.groups[i]

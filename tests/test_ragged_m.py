@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import given, mixed_chain, settings, st
+from conftest import given, launch_bm, mixed_chain, settings, st
 
 from repro import kernels as K
 from repro.configs import get_reduced
@@ -212,7 +212,8 @@ def test_ragged_chained_bitmatches_per_request_oracle(bucket, dtype):
                                           h=h, w=w, m_valid=mv)
         oracle = kops.grouped_matmul_chained(phases(x0[:mv]), m=mv,
                                              h=h, w=w)
-        _assert_chained_ragged(got, oracle, mv)
+        _assert_chained_ragged(got, oracle, mv,
+                               launch_bm(phases(x0), x0.shape[0], h, w))
 
 
 def test_ragged_chained_traced_m_valid_shares_one_executable():
@@ -230,25 +231,27 @@ def test_ragged_chained_traced_m_valid_shares_one_executable():
         got = run(jnp.int32(mv))
         oracle = kops.grouped_matmul_chained(phases(x0[:mv]), m=mv,
                                              h=8, w=8)
-        _assert_chained_ragged(got, oracle, mv)
+        _assert_chained_ragged(got, oracle, mv,
+                               launch_bm(phases(x0), x0.shape[0], 8, 8))
     assert len(traces) == 1, "chained m_valid retraced per value"
 
 
 def test_ragged_chained_dead_blocks_execute_zero_steps():
     """The no-op guard SKIPS dead M-blocks — it does not merely zero
-    them.  rows/image == bm (h*w = 128) makes image count == block
-    count, so the grid-step counter must read exactly the live blocks'
-    share of the table and the skip ratio is exactly 1 - n/bucket."""
+    them.  rows/image == bm (h*w = 128, a 128-row M-block) makes image
+    count == block count, so the grid-step counter must read exactly the
+    live blocks' share of the table and the skip ratio is exactly
+    1 - n/bucket."""
     b, h, w = 4, 16, 8          # 128 rows/image == bm: 4 images, 4 blocks
     x0, phases = _chain_case(b, h, w)
     m = b * h * w
-    spec = gmm._chain_static(phases(x0), 128, 128, w)
+    spec = gmm._chain_static(phases(x0), 128, w)
     tab = np.asarray(gmm._plan_tiles_chained(m // 128, spec))
     total = tab.shape[1]
     from repro.analysis import tables
     for vi in (1, 2, 3, 4):
         _, steps = gmm.grouped_matmul_chained(
-            phases(x0), m=m, h=h, w=w, m_valid=vi * h * w,
+            phases(x0), m=m, h=h, w=w, bm=128, m_valid=vi * h * w,
             debug_steps=True, interpret=True)
         executed = int(np.asarray(steps)[0, 0])
         expected = int((tab[tables.CH_I] < vi).sum())
@@ -267,16 +270,17 @@ def test_ragged_chained_mixed_sources_skip_dead_steps_and_windows(
     the live blocks' steps and builds only their ring windows."""
     phases, x, panel, m = mixed_chain()
     mv = valid_images * 14 * 14
-    live = -(-mv // 128)
+    bm = launch_bm(phases(x), m, 14, 14)
+    live = -(-mv // bm)
     got, cnt = gmm.grouped_matmul_chained(
         phases(x), m=m, h=14, w=14, panels=[panel], m_valid=mv,
         debug_steps=True, interpret=True)
     oracle = gmm.grouped_matmul_chained(
         phases(x[:mv]), m=mv, h=14, w=14, panels=[panel[:mv]],
         interpret=True)
-    _assert_chained_ragged(got, oracle, mv)
-    spec = gmm._chain_static(phases(x), 128, 128, 14)
-    tab = np.asarray(gmm._plan_tiles_chained(-(-m // 128), spec))
+    _assert_chained_ragged(got, oracle, mv, bm)
+    spec = gmm._chain_static(phases(x), 128, 14)
+    tab = np.asarray(gmm._plan_tiles_chained(-(-m // bm), spec))
     from repro.analysis import tables
     cnt = np.asarray(cnt)
     assert int(cnt[0, 0]) == int((tab[tables.CH_I] < live).sum())

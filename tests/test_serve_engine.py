@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis import budgets
 from repro.core import plan_cache
 from repro.launch import serve
 from repro.models.cnn import CNNConfig, InceptionSpec, init_params
@@ -107,7 +108,9 @@ def test_setup_counts_the_chained_launches_steps():
     their offset tables as the warm dispatch traces the step; an engine
     whose executables were traced before it reads the same counts from an
     abstract trace.  The window builds sit far below the ring steps: one
-    per (phase, block, ring column), not one per tap."""
+    per (phase, block, ring column), not one per tap.  ``chained_bm``
+    holds each chained launch's (M-block, M-block count), the planner's
+    for each of the bucket's chained groups."""
     from repro.configs import get_reduced
     cfg = get_reduced("googlenet")
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -120,7 +123,13 @@ def test_setup_counts_the_chained_launches_steps():
     s = first.setup
     assert again.setup["chained_steps"] == s["chained_steps"]
     assert again.setup["ring_window_builds"] == s["ring_window_builds"]
+    assert again.setup["chained_bm"] == s["chained_bm"]
     for b in first.ladder:
         steps, builds = s["chained_steps"][b], s["ring_window_builds"][b]
         assert steps["x"] > 0 and steps["ring"] > 0
         assert 0 < builds * 9 <= steps["ring"]
+        plan = first.entries[b].plan
+        graph = plan.context["graph"]
+        assert s["chained_bm"][b] == [
+            budgets.chained_spec(graph, g.chain, g.pools)[:2]
+            for g in plan.groups if g.mode == "grouped_chained"]

@@ -7,12 +7,16 @@ The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import importlib
+import re
+
 import pytest
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis import budgets
 from repro.configs import get_config
 from repro.core import cost_model as cm
 from repro.core import plan as planlib
@@ -20,6 +24,7 @@ from repro.kernels import ops
 from repro.models import cnn as CNN
 
 CFG = get_config("googlenet")
+gmm = importlib.import_module("repro.kernels.grouped_matmul")
 
 
 @pytest.fixture(scope="module")
@@ -80,28 +85,51 @@ def _group(plan, first_op):
     return next(g for g in plan.groups if g.ops[0] == first_op)
 
 
-def test_stem_chain_serving_bucket8(one_chip):
-    # refused at bucket 8 before SMEM chunking: the stem chain's offset
-    # table alone is 1.5 MiB
+def _compile_chained(one_chip, first_op, seed, shape):
+    """Compile the bucket-8 serving plan's chained group ``first_op``,
+    seeded with ``seed`` of ``shape``: one kernel per SMEM chunk, each
+    under the VMEM limit of the launch at the M-block the planner gives
+    it.  Returns the group."""
     plan, _ = CNN.plan_cnn(CFG, 8, chain_modules=True)
-    stem = _group(plan, "stem0")
-    assert stem.mode == "grouped_chained" and stem.chunks > 1
-    n = _compile(_run_group(plan, stem, ["input"]), _params(one_chip),
-                 [_sds((8,) + CFG.img, one_chip)], _sds((), one_chip,
-                                                        jnp.int32))
-    assert n == stem.chunks
+    g = _group(plan, first_op)
+    assert g.mode == "grouped_chained"
+    bm, _mb, spec, _oh, ow, _nring, _ = budgets.chained_spec(
+        plan.context["graph"], g.chain, g.pools)
+    fn = _run_group(plan, g, [seed])
+    args = (_params(one_chip), [_sds(shape, one_chip)],
+            _sds((), one_chip, jnp.int32))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = [{int(v) for v in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+        r'"size":"(\d+)"', line)} for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    limit = gmm.chained_vmem_limit(gmm.chained_vmem_bytes(spec, bm, ow, 4))
+    assert len(kernels) == g.chunks
+    assert all(limit in sizes for sizes in kernels), (limit, kernels)
+    return g
+
+
+def test_stem_chain_serving_bucket8(one_chip):
+    # refused at bucket 8 with 128-row M-blocks and no SMEM chunking (a
+    # 1.5 MiB offset table); at its 1024-row M-blocks the table fits one
+    # launch
+    stem = _compile_chained(one_chip, "stem0", "input", (8,) + CFG.img)
+    assert stem.chunks == 1
 
 
 def test_ragged_chained_module_bucket8(one_chip):
     # inc1 (3b) at bucket 8: a two-phase chained launch with a traced
-    # m_valid, its pool-proj pool folded in, split into SMEM chunks
-    plan, _ = CNN.plan_cnn(CFG, 8, chain_modules=True)
-    inc1 = _group(plan, "inc1/r5")
-    assert inc1.mode == "grouped_chained"
-    n = _compile(_run_group(plan, inc1, ["inc0/join"]), _params(one_chip),
-                 [_sds((8, 56, 56, 256), one_chip)],
-                 _sds((), one_chip, jnp.int32))
-    assert n == inc1.chunks
+    # m_valid, its pool-proj pool folded in
+    _compile_chained(one_chip, "inc1/r5", "inc0/join", (8, 56, 56, 256))
+
+
+@pytest.mark.parametrize("first_op,seed,shape", [
+    ("inc6/r3", "inc5/join", (8, 28, 28, 528)),    # 4e: 3 ring columns
+    ("inc8/r3", "inc7/join", (8, 14, 14, 832)),    # 5b: the widest
+])
+def test_ragged_chained_module_bucket8_at_its_block_m(one_chip, first_op,
+                                                      seed, shape):
+    _compile_chained(one_chip, first_op, seed, shape)
 
 
 def test_inc1_combined_backward_batch32(one_chip):
